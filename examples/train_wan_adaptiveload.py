@@ -35,6 +35,7 @@ from repro.distributed.fault_tolerance import (
     FaultTolerantRunner,
     HeartbeatMonitor,
 )
+from repro.launch.cache import enable_compilation_cache
 from repro.models.config import ModelConfig
 from repro.optim.adamw import OptimizerConfig
 from repro.train.loop import Trainer, deserialize_rng_key
@@ -56,6 +57,7 @@ def main() -> None:
     if args.straggler != 1.0 and args.workers < 2:
         ap.error("--straggler needs --workers >= 2: straggler detection "
                  "compares a rank against its peers on the same shapes")
+    enable_compilation_cache()
 
     # ~100M-param Wan-style MMDiT (18 layers, d=512 -> 101M params)
     cfg = ModelConfig(

@@ -48,7 +48,6 @@ import hashlib
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.dispatch import (
@@ -59,7 +58,11 @@ from repro.core.dispatch import (
 from repro.core.telemetry import WorkerStepRecord
 from repro.models.config import ModelConfig
 from repro.optim.adamw import OptimizerConfig, adamw_update
-from repro.train.steps import make_pool_grad_step, make_sp_pool_grad_step
+from repro.train.steps import (
+    make_pool_grad_step,
+    make_pool_update,
+    make_sp_pool_grad_step,
+)
 
 WorkerSteps = Sequence[Sequence[tuple[Any, dict]]]  # [rank][(bucket, batch)]
 
@@ -225,12 +228,12 @@ class PlanExecutor:
             lambda t: jax.tree.map(lambda g: g[None].astype(jnp.float32), t)
         )
         self._gather_digests = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda d: jax.lax.all_gather(d[0], "data", axis=0),
                 mesh=mesh,
                 in_specs=P("data"),
                 out_specs=P(),
-                check_rep=False,  # all_gather output replication isn't inferred
+                check_vma=False,  # all_gather output replication isn't inferred
             )
         )
         self._update = None  # built lazily (needs the state tree structure)
@@ -487,12 +490,12 @@ class PlanExecutor:
                 return sp(params, batch, step_key, idx)
 
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=submesh,
                     in_specs=(P(),) + (P(None, "seq"),) * 4 + (P(), P()),
                     out_specs=(P(), P()),
-                    check_rep=False,  # psum/ppermute defeat rep inference
+                    check_vma=False,  # psum/ppermute defeat rep inference
                 )
             )
             self._sp_steps[key] = (submesh, fn)
@@ -578,7 +581,7 @@ class PlanExecutor:
                     lambda g: jax.lax.psum(jnp.squeeze(g, 0), "data"), tree
                 )
 
-            reduce = shard_map(
+            reduce = jax.shard_map(
                 local_sum,
                 mesh=self.mesh,
                 in_specs=P("data"),
@@ -602,6 +605,24 @@ class PlanExecutor:
             reduce_and_update,
             donate_argnums=(0,) if self._donate else (),
         )
+
+    def lower_update(self, state):
+        """Lower the reduce-and-update program for a train state of
+        ``state``'s shapes (arrays or ``ShapeDtypeStruct``s) without
+        placing anything — its ``compile().memory_analysis()`` gives the
+        per-device bytes of the step's largest program."""
+        def on(sharding, shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        st = jax.tree.map(
+            lambda x: on(self._replicated, x.shape, x.dtype), state
+        )
+        grads = jax.tree.map(  # what _lift stacks: [n_ranks, ...] fp32
+            lambda p: on(self._stacked, (self.n_ranks,) + p.shape, jnp.float32),
+            st["params"],
+        )
+        stats = on(self._stacked, (self.n_ranks, 2), jnp.float32)
+        return self._build_update(st).lower(st, grads, stats)
 
     def _stack(self, per_rank_trees):
         """[rank] trees of [1, ...] device-local leaves -> one mesh array
@@ -895,16 +916,7 @@ def oracle_step(cfg: ModelConfig, opt: OptimizerConfig, state, worker_steps,
             )
             loss_sum = loss_sum + loss
             n += 1
-    grads = jax.tree.map(lambda g: g.astype(jnp.float32) / n, acc)
-    new_params, new_opt, stats = adamw_update(
-        state["params"], grads, state["opt"], state["step"], opt
-    )
-    new_state = {
-        "params": new_params,
-        "opt": new_opt,
-        "step": state["step"] + 1,
-    }
-    return new_state, {"loss": loss_sum / n, **stats}
+    return make_pool_update(opt)(state, acc, loss_sum, n)
 
 
 def rel_l2(a, b) -> float:

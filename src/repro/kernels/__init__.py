@@ -1,20 +1,26 @@
 """Kernel dispatch layer.
 
-Models call these wrappers; a process-wide backend switch selects between
+Models call these wrappers; the backend is chosen by platform the first
+time a wrapper traces (never at import):
 
-* ``"ref"``    — fused ``jax.custom_vjp`` jnp implementations (CPU default;
-                 these already deliver the paper's *graph-level* fusion —
-                 minimal residuals — and are the numeric oracles), and
-* ``"pallas"`` — the TPU Pallas kernels (``interpret=True`` on CPU for
-                 validation; compiled on real TPU).
+* ``"pallas"`` on TPU — the Pallas kernels, compiled by Mosaic.  A shape
+  the kernels cannot tile raises :class:`NotImplementedError` instead of
+  quietly running a jnp oracle on the chip;
+* ``"ref"`` elsewhere — fused ``jax.custom_vjp`` jnp implementations (these
+  already deliver the paper's *graph-level* fusion — minimal residuals —
+  and are the numeric oracles).
 
-Use ``set_backend("pallas")`` or the ``REPRO_KERNEL_BACKEND`` env var.
+``set_backend`` overrides the choice for tests and benchmarks:
+``"pallas_interpret"`` runs the kernels' tile programs on CPU (fallbacks
+then warn with :class:`KernelFallbackWarning`), ``"naive"`` runs the
+paper's discrete-op baseline.
 """
 
 from __future__ import annotations
 
-import os
+import jax
 
+from .fallback import KernelFallbackWarning, kernel_fallback
 from .fused_adaln.ref import (
     activation_bytes_fused,
     activation_bytes_naive,
@@ -30,55 +36,51 @@ from .fused_rmsnorm.ref import (
     rms_norm_naive,
 )
 
-_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "ref")
 # "naive" = discrete ops, no fused VJP (the paper's baseline);
-# "ref"   = fused custom_vjp jnp (graph-level fusion, CPU default);
-# "pallas"/"pallas_interpret" = the TPU kernels.
+# "ref"   = fused custom_vjp jnp (graph-level fusion, the non-TPU default);
+# "pallas" = the compiled TPU kernels (the TPU default);
+# "pallas_interpret" = the same kernels interpreted on CPU (tests).
 _VALID = ("naive", "ref", "pallas", "pallas_interpret")
+_override: str | None = None  # set_backend's choice; None = by platform
 
 
 def set_backend(name: str) -> None:
-    global _BACKEND
+    global _override
     if name not in _VALID:
         raise ValueError(f"backend must be one of {_VALID}, got {name!r}")
-    _BACKEND = name
+    _override = name
 
 
 def get_backend() -> str:
-    return _BACKEND
+    if _override is not None:
+        return _override
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _pallas() -> bool:
+    return get_backend().startswith("pallas")
 
 
 def _interpret() -> bool:
-    return _BACKEND == "pallas_interpret"
+    return get_backend() == "pallas_interpret"
 
 
 def adaln_modulate(x, scale, shift, eps: float = 1e-6):
     """Fused LayerNorm-Modulate (paper §3.3)."""
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         from .fused_adaln.ops import adaln_modulate as op
 
         return op(x, scale, shift, eps=eps, interpret=_interpret())
-    if _BACKEND == "naive":
+    if get_backend() == "naive":
         return adaln_naive(x, scale, shift, eps)
     return adaln_fused_ref(x, scale, shift, eps)
 
 
-_flash_fallback_warned: set = set()
-
-
-def _warn_flash_fallback(dh: int) -> None:
-    """Pallas backend requested but the flash kernel can't tile this head
-    dim; say so once per shape instead of silently using the jnp path."""
-    if dh not in _flash_fallback_warned:
-        _flash_fallback_warned.add(dh)
-        import warnings
-
-        warnings.warn(
-            f"pallas backend: flash attention needs head_dim % 128 == 0 "
-            f"(got dh={dh}); using the jnp blocked_attention path for this "
-            f"shape",
-            stacklevel=3,
-        )
+def _head_dim_fallback(kernel: str, dh: int) -> None:
+    kernel_fallback(
+        f"{kernel} needs head_dim % 128 == 0 (got dh={dh})",
+        interpret=_interpret(),
+    )
 
 
 def attention(
@@ -116,7 +118,7 @@ def attention(
     if hq % hkv != 0:  # no backend can group these heads
         raise ValueError(f"GQA needs Hq % Hkv == 0, got Hq={hq}, Hkv={hkv}")
     if seq_axis is not None:
-        if _BACKEND.startswith("pallas") and dh % 128 == 0:
+        if _pallas() and dh % 128 == 0:
             from .flash_attention.ring import ring_flash_attention
 
             out = ring_flash_attention(
@@ -126,8 +128,8 @@ def attention(
                 interpret=_interpret(),
             )
             return out.swapaxes(1, 2)
-        if _BACKEND.startswith("pallas"):
-            _warn_flash_fallback(dh)
+        if _pallas():
+            _head_dim_fallback("ring flash attention", dh)
         from .flash_attention.ring import ring_attention_ref
 
         out = ring_attention_ref(
@@ -136,7 +138,7 @@ def attention(
             axis_name=seq_axis, causal=causal, scale=scale,
         )
         return out.swapaxes(1, 2)
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         if dh % 128 == 0:
             from .flash_attention.ops import flash_attention
 
@@ -146,7 +148,7 @@ def attention(
                 causal=causal, scale=scale, interpret=_interpret(),
             )
             return out.swapaxes(1, 2)
-        _warn_flash_fallback(dh)
+        _head_dim_fallback("flash attention", dh)
     g = hq // hkv
     return blocked_attention(
         q, repeat_kv(k, g), repeat_kv(v, g),
@@ -175,7 +177,7 @@ def paged_attention(
     hkv = k_pages.shape[2]
     if hq % hkv != 0:
         raise ValueError(f"GQA needs Hq % Hkv == 0, got Hq={hq}, Hkv={hkv}")
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         if dh % 128 == 0:
             from .flash_attention.paged import paged_attention_pallas
 
@@ -183,7 +185,7 @@ def paged_attention(
                 q, k_pages, v_pages, page_table, kv_lens,
                 scale=scale, interpret=_interpret(),
             )
-        _warn_flash_fallback(dh)
+        _head_dim_fallback("paged attention", dh)
     from .flash_attention.paged import paged_attention_ref
 
     return paged_attention_ref(
@@ -192,41 +194,42 @@ def paged_attention(
 
 
 def rms_norm(x, w, eps: float = 1e-6):
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         from .fused_rmsnorm.ops import rms_norm as op
 
         return op(x, w, eps=eps, interpret=_interpret())
-    if _BACKEND == "naive":
+    if get_backend() == "naive":
         return rms_norm_naive(x, w, eps)
     return rms_norm_fused_ref(x, w, eps)
 
 
 def gated_rms_norm(x, w, gate, eps: float = 1e-6):
     """rmsnorm(x) * w * silu(gate) — paper's Gate+Norm fusion."""
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         from .fused_rmsnorm.ops import gated_rms_norm as op
 
         return op(x, w, gate, eps=eps, interpret=_interpret())
-    if _BACKEND == "naive":
+    if get_backend() == "naive":
         return gated_rms_norm_naive(x, w, gate, eps)
     return gated_rms_norm_fused_ref(x, w, gate, eps)
 
 
 def qk_norm(q, k, wq, wk, eps: float = 1e-6):
     """Joint per-head q/k RMSNorm — paper's QNorm+KNorm fusion."""
-    if _BACKEND.startswith("pallas"):
+    if _pallas():
         from .fused_rmsnorm.ops import rms_norm as op
 
         return (
             op(q, wq, eps=eps, interpret=_interpret()),
             op(k, wk, eps=eps, interpret=_interpret()),
         )
-    if _BACKEND == "naive":
+    if get_backend() == "naive":
         return (rms_norm_naive(q, wq, eps), rms_norm_naive(k, wk, eps))
     return qk_norm_naive(q, k, wq, wk, eps)
 
 
 __all__ = [
+    "KernelFallbackWarning",
     "set_backend",
     "get_backend",
     "attention",

@@ -77,9 +77,36 @@ def _masks(s_shape, qi, kj, causal, qs_ref, ks_ref):
         k_pos = kj * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
         mask = q_pos >= k_pos
     if qs_ref is not None:
-        seg = qs_ref[0][:, None] == ks_ref[0][None, :]
+        # [qb, 1] column against a [1, kb] row: lane and sublane broadcasts
+        seg = qs_ref[...] == ks_ref[...]
         mask = seg if mask is None else (mask & seg)
     return mask
+
+
+# Block layout.  The TPU compiler wants the last two dims of every block
+# (8, 128)-divisible or equal to the array's, so per-row data never travels
+# as a [B, S] or [B, H, S] array with (1, blk) blocks:
+#
+# * per-q-row values (LSE, delta, q segment ids) are [..., S, 1] columns —
+#   softmax statistics come out of lane reductions sublane-major, so they
+#   store and broadcast against [qb, kb] score tiles with no relayout;
+# * kv segment ids are a [B, 1, S] row, broadcast down the sublanes;
+# * batch and head dims are squeezed (``None``) out of every block.
+#
+# The public functions keep the [B, H, S] / [B, S] interfaces; the reshapes
+# to and from these layouts are free.
+
+
+def _q_col(qb, index_map):
+    return pl.BlockSpec((None, qb, 1), index_map)
+
+
+def _kv_row(kb, index_map):
+    return pl.BlockSpec((None, 1, kb), index_map)
+
+
+def _seg_operands(q_segment_ids, kv_segment_ids):
+    return [q_segment_ids[:, :, None], kv_segment_ids[:, None, :]]
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +122,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, kv_tiles, causal, has_segment
         qs_ref = ks_ref = None
     qi = pl.program_id(2)
     kj = pl.program_id(3)
-    qb, kb = q_ref.shape[2], k_ref.shape[2]
+    qb, kb = q_ref.shape[0], k_ref.shape[0]
 
     @pl.when(kj == 0)
     def _init():
@@ -109,28 +136,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, kv_tiles, causal, has_segment
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [qb, dh]
-        k = k_ref[0, 0].astype(jnp.float32)  # [kb, dh]
-        v = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale  # [qb, dh]
+        k = k_ref[...].astype(jnp.float32)  # [kb, dh]
+        v = v_ref[...].astype(jnp.float32)
         s = q @ k.T  # [qb, kb]
         mask = _masks(s.shape, qi, kj, causal, qs_ref, ks_ref)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_scr[...]  # [qb, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)  # exp(NEG_INF - NEG_INF) guard
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + p @ v
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + p @ v
         m_scr[...] = m_new
 
     @pl.when(kj == kv_tiles - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], LSE_FLOOR)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(denom)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[...] + jnp.log(denom)
 
 
 def flash_attention_fwd_pallas(
@@ -166,18 +193,18 @@ def flash_attention_fwd_pallas(
 
     from jax.experimental.pallas import tpu as pltpu
 
-    in_specs = [
-        pl.BlockSpec((1, 1, qb, dh), lambda bi, h, i, j: (bi, h, i, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)),
-    ]
+    q_tile = pl.BlockSpec((None, None, qb, dh), lambda bi, h, i, j: (bi, h, i, 0))
+    kv_tile = pl.BlockSpec(
+        (None, None, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)
+    )
+    in_specs = [q_tile, kv_tile, kv_tile]
     operands = [q, k, v]
     if has_segments:
         in_specs += [
-            pl.BlockSpec((1, qb), lambda bi, h, i, j: (bi, i)),
-            pl.BlockSpec((1, kb), lambda bi, h, i, j: (bi, j)),
+            _q_col(qb, lambda bi, h, i, j: (bi, i, 0)),
+            _kv_row(kb, lambda bi, h, i, j: (bi, 0, j)),
         ]
-        operands += [q_segment_ids, kv_segment_ids]
+        operands += _seg_operands(q_segment_ids, kv_segment_ids)
 
     out, lse = pl.pallas_call(
         functools.partial(
@@ -190,21 +217,21 @@ def flash_attention_fwd_pallas(
         grid=(b, hq, sq // qb, kv_tiles),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, qb, dh), lambda bi, h, i, j: (bi, h, i, 0)),
-            pl.BlockSpec((1, 1, qb), lambda bi, h, i, j: (bi, h, i)),
+            q_tile,
+            pl.BlockSpec((None, None, qb, 1), lambda bi, h, i, j: (bi, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, dh), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((qb,), jnp.float32),
-            pltpu.VMEM((qb,), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, dh), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
-    return out, lse
+    return out, lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -220,22 +247,22 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ds = p * (do @ v^T - delta) — d(scores), with masked entries exactly 0 so
     padded/foreign-segment positions contribute nothing to any gradient.
     """
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]  # [qb]
-    delta = delta_ref[0, 0]  # [qb]
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    do = do_ref[...].astype(jnp.float32)
+    lse = lse_ref[...]  # [qb, 1]
+    delta = delta_ref[...]  # [qb, 1]
     s = (q @ k.T) * scale
     mask = _masks(s.shape, qi, kj, causal, qs_ref, ks_ref)
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     if mask is not None:
         # fully-masked rows have lse == NEG_INF -> exp(0) == 1; zero them.
         p = jnp.where(mask, p, 0.0)
     dp = do @ v.T  # [qb, kb]
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     return q, k, do, p, ds
 
 
@@ -253,7 +280,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         qs_ref = ks_ref = None
     qi = pl.program_id(2)
     kj = pl.program_id(3)
-    qb, kb = q_ref.shape[2], k_ref.shape[2]
+    qb, kb = q_ref.shape[0], k_ref.shape[0]
 
     @pl.when(kj == 0)
     def _init():
@@ -273,7 +300,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     @pl.when(kj == kv_tiles - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def flash_attention_bwd_dq_pallas(
@@ -296,21 +323,19 @@ def flash_attention_bwd_dq_pallas(
 
     from jax.experimental.pallas import tpu as pltpu
 
-    in_specs = [
-        pl.BlockSpec((1, 1, qb, dh), lambda bi, h, i, j: (bi, h, i, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)),
-        pl.BlockSpec((1, 1, qb, dh), lambda bi, h, i, j: (bi, h, i, 0)),
-        pl.BlockSpec((1, 1, qb), lambda bi, h, i, j: (bi, h, i)),
-        pl.BlockSpec((1, 1, qb), lambda bi, h, i, j: (bi, h, i)),
-    ]
-    operands = [q, k, v, do, lse, delta]
+    q_tile = pl.BlockSpec((None, None, qb, dh), lambda bi, h, i, j: (bi, h, i, 0))
+    kv_tile = pl.BlockSpec(
+        (None, None, kb, dh), lambda bi, h, i, j, g=g: (bi, h // g, j, 0)
+    )
+    row = pl.BlockSpec((None, None, qb, 1), lambda bi, h, i, j: (bi, h, i, 0))
+    in_specs = [q_tile, kv_tile, kv_tile, q_tile, row, row]
+    operands = [q, k, v, do, lse[..., None], delta[..., None]]
     if has_segments:
         in_specs += [
-            pl.BlockSpec((1, qb), lambda bi, h, i, j: (bi, i)),
-            pl.BlockSpec((1, kb), lambda bi, h, i, j: (bi, j)),
+            _q_col(qb, lambda bi, h, i, j: (bi, i, 0)),
+            _kv_row(kb, lambda bi, h, i, j: (bi, 0, j)),
         ]
-        operands += [q_segment_ids, kv_segment_ids]
+        operands += _seg_operands(q_segment_ids, kv_segment_ids)
 
     return pl.pallas_call(
         functools.partial(
@@ -322,7 +347,7 @@ def flash_attention_bwd_dq_pallas(
         ),
         grid=(b, hq, sq // qb, kv_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qb, dh), lambda bi, h, i, j: (bi, h, i, 0)),
+        out_specs=q_tile,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((qb, dh), jnp.float32)],
         interpret=interpret,
@@ -346,7 +371,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     kj = pl.program_id(2)
     gi = pl.program_id(3)
     qi = pl.program_id(4)
-    qb, kb = q_ref.shape[2], k_ref.shape[2]
+    qb, kb = q_ref.shape[0], k_ref.shape[0]
 
     @pl.when((gi == 0) & (qi == 0))
     def _init():
@@ -368,8 +393,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     @pl.when((gi == group - 1) & (qi == q_tiles - 1))
     def _finalize():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd_dkv_pallas(
@@ -395,21 +420,21 @@ def flash_attention_bwd_dkv_pallas(
     def qhead(h, gi, g=g):
         return h * g + gi
 
-    in_specs = [
-        pl.BlockSpec((1, 1, qb, dh), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, j, gi, i: (bi, h, j, 0)),
-        pl.BlockSpec((1, 1, kb, dh), lambda bi, h, j, gi, i: (bi, h, j, 0)),
-        pl.BlockSpec((1, 1, qb, dh), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i, 0)),
-        pl.BlockSpec((1, 1, qb), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i)),
-        pl.BlockSpec((1, 1, qb), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i)),
-    ]
-    operands = [q, k, v, do, lse, delta]
+    q_tile = pl.BlockSpec(
+        (None, None, qb, dh), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i, 0)
+    )
+    kv_tile = pl.BlockSpec((None, None, kb, dh), lambda bi, h, j, gi, i: (bi, h, j, 0))
+    row = pl.BlockSpec(
+        (None, None, qb, 1), lambda bi, h, j, gi, i: (bi, qhead(h, gi), i, 0)
+    )
+    in_specs = [q_tile, kv_tile, kv_tile, q_tile, row, row]
+    operands = [q, k, v, do, lse[..., None], delta[..., None]]
     if has_segments:
         in_specs += [
-            pl.BlockSpec((1, qb), lambda bi, h, j, gi, i: (bi, i)),
-            pl.BlockSpec((1, kb), lambda bi, h, j, gi, i: (bi, j)),
+            _q_col(qb, lambda bi, h, j, gi, i: (bi, i, 0)),
+            _kv_row(kb, lambda bi, h, j, gi, i: (bi, 0, j)),
         ]
-        operands += [q_segment_ids, kv_segment_ids]
+        operands += _seg_operands(q_segment_ids, kv_segment_ids)
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -422,10 +447,7 @@ def flash_attention_bwd_dkv_pallas(
         ),
         grid=(b, hkv, skv // kb, g, q_tiles),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, kb, dh), lambda bi, h, j, gi, i: (bi, h, j, 0)),
-            pl.BlockSpec((1, 1, kb, dh), lambda bi, h, j, gi, i: (bi, h, j, 0)),
-        ],
+        out_specs=[kv_tile, kv_tile],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, skv, dh), k.dtype),
             jax.ShapeDtypeStruct((b, hkv, skv, dh), v.dtype),

@@ -21,10 +21,19 @@ Layout:
 * ``kv_lens``  — ``[B]`` int32: valid tokens per slot.  ``kv_lens == 0``
   rows emit exact zeros (inactive decode slots).
 
-Grid = (B, Hkv, pages_max) with the page sweep innermost ("arbitrary"
-semantics).  The page table and kv_lens ride in scalar-prefetch slots so
-the k/v BlockSpec index maps can chase ``table[b, j]`` — the pool page is
-DMA'd directly; no gather materializes the contiguous cache.
+Grid = (B, pages_max) with the page sweep innermost ("arbitrary"
+semantics); one grid step takes every kv head of a page.  The page table
+and kv_lens ride in scalar-prefetch slots so the k/v BlockSpec index maps
+can chase ``table[b, j]`` — the pool page is DMA'd directly; no gather
+materializes the contiguous cache.
+
+Block layout: a per-head block would be ``(g, dh)`` of q and
+``(ps, 1, dh)`` of a page, and the TPU compiler refuses both (the last two
+block dims must be (8, 128)-divisible or whole).  So q comes whole
+(``[Hq, dh]``) and each page is viewed as ``[ps, Hkv * dh]`` (a free
+reshape), from which kv head ``h`` is the lane-aligned slice
+``[:, h*dh:(h+1)*dh]``.  Query rows of group ``h`` select their scores and
+values with a row mask, so no unaligned sublane slice is needed.
 
 Non-causal by construction: the query is the newest token, every cached
 slot ``< kv_len`` is visible.  Forward only — decode needs no backward.
@@ -47,22 +56,25 @@ from .flash import LSE_FLOOR, NEG_INF
 def _paged_kernel(
     table_ref,  # scalar prefetch: [B, pages_max] int32
     lens_ref,  # scalar prefetch: [B] int32
-    q_ref,  # [1, g, dh]
-    k_ref,  # [1, ps, 1, dh]
-    v_ref,  # [1, ps, 1, dh]
-    o_ref,  # [1, g, dh]
-    m_scr,  # VMEM [g] f32
-    l_scr,  # VMEM [g] f32
-    acc_scr,  # VMEM [g, dh] f32
+    q_ref,  # [Hq, dh]
+    k_ref,  # [ps, Hkv * dh]
+    v_ref,  # [ps, Hkv * dh]
+    o_ref,  # [Hq, dh]
+    m_scr,  # VMEM [Hq, 1] f32
+    l_scr,  # VMEM [Hq, 1] f32
+    acc_scr,  # VMEM [Hq, dh] f32
     *,
     scale: float,
     pages_max: int,
     page_size: int,
+    n_kv_heads: int,
 ):
     del table_ref  # consumed by the k/v index maps
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     ctx = lens_ref[b]
+    hq, dh = q_ref.shape
+    g = hq // n_kv_heads
 
     @pl.when(j == 0)
     def _init():
@@ -70,33 +82,42 @@ def _paged_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def group_rows(shape, h):
+        """Rows of q head group ``h`` (the q heads sharing kv head h)."""
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        return (row >= h * g) & (row < (h + 1) * g)
+
     # page tile-skip: the paged analog of flash.py's _tile_overlap —
     # logical page j holds slots [j*ps, (j+1)*ps); it is dead past ctx
     @pl.when(j * page_size < ctx)
     def _compute():
-        g = q_ref.shape[1]
-        q = q_ref[0].astype(jnp.float32) * scale  # [g, dh]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [ps, dh]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = q @ k.T  # [g, ps]
+        q = q_ref[...].astype(jnp.float32) * scale  # [Hq, dh]
+        s = jnp.zeros((hq, page_size), jnp.float32)
+        for h in range(n_kv_heads):
+            k = k_ref[:, h * dh:(h + 1) * dh].astype(jnp.float32)  # [ps, dh]
+            s = jnp.where(group_rows(s.shape, h), q @ k.T, s)
         slot = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, page_size), 1
+            jnp.int32, (hq, page_size), 1
         )
         mask = slot < ctx
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)  # exp(NEG_INF - NEG_INF) guard
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + p @ v
+        pv = jnp.zeros((hq, dh), jnp.float32)
+        for h in range(n_kv_heads):
+            v = v_ref[:, h * dh:(h + 1) * dh].astype(jnp.float32)
+            pv = jnp.where(group_rows(pv.shape, h), p @ v, pv)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + pv
         m_scr[...] = m_new
 
     @pl.when(j == pages_max - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], LSE_FLOOR)
-        o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -114,34 +135,30 @@ def paged_attention_pallas(
     p_pool, ps, hkv, dh_k = k_pages.shape
     assert dh == dh_k and dh % 128 == 0
     assert hq % hkv == 0
-    g = hq // hkv
     pages_max = page_table.shape[1]
     scale = scale if scale is not None else dh**-0.5
 
     from jax.experimental.pallas import tpu as pltpu
 
+    rows = pl.BlockSpec((None, hq, dh), lambda bi, j, t, n: (bi, 0, 0))
+    page = pl.BlockSpec(
+        (None, ps, hkv * dh), lambda bi, j, t, n: (t[bi, j], 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, pages_max),
-        in_specs=[
-            pl.BlockSpec((1, g, dh), lambda bi, h, j, t, n: (bi, h, 0)),
-            pl.BlockSpec(
-                (1, ps, 1, dh), lambda bi, h, j, t, n: (t[bi, j], 0, h, 0)
-            ),
-            pl.BlockSpec(
-                (1, ps, 1, dh), lambda bi, h, j, t, n: (t[bi, j], 0, h, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, g, dh), lambda bi, h, j, t, n: (bi, h, 0)),
+        grid=(b, pages_max),
+        in_specs=[rows, page, page],
+        out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, dh), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(
-            _paged_kernel, scale=scale, pages_max=pages_max, page_size=ps
+            _paged_kernel, scale=scale, pages_max=pages_max, page_size=ps,
+            n_kv_heads=hkv,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
@@ -150,8 +167,8 @@ def paged_attention_pallas(
         page_table.astype(jnp.int32),
         kv_lens.astype(jnp.int32),
         q,
-        k_pages,
-        v_pages,
+        k_pages.reshape(p_pool, ps, hkv * dh),
+        v_pages.reshape(p_pool, ps, hkv * dh),
     )
 
 

@@ -6,6 +6,7 @@ import functools
 
 import jax
 
+from ..fallback import kernel_fallback
 from .adaln import (
     DEFAULT_D_BLOCK,
     DEFAULT_DMOD_SEQ_BLOCK,
@@ -17,17 +18,21 @@ from .adaln import (
 from .ref import adaln_fused_ref
 
 
-def _divisor_block(n: int, target: int) -> int:
-    """Largest divisor of ``n`` that is <= ``target``.
+def _divisor_block(n: int, target: int, granule: int = 8) -> int:
+    """Largest divisor of ``n`` that is <= ``target`` and a multiple of
+    ``granule`` (8 for a sublane dim, 128 for a lane dim: the TPU block
+    rule); ``n`` itself when ``n <= target``.
 
     Never exceeds the VMEM-safe ``target``; for awkward ``n`` (e.g. prime)
-    this bottoms out at 1 and ``_pallas_supported`` routes to the jnp ref
+    this bottoms out at 1 and ``_pallas_supported`` refuses the shape
     instead of letting a huge degenerate block blow up VMEM.
     """
-    blk = min(target, n)
-    while blk > 1 and n % blk != 0:
-        blk -= 1
-    return blk
+    if n <= target:
+        return n
+    for blk in range(target - target % granule, 0, -granule):
+        if n % blk == 0:
+            return blk
+    return 1
 
 
 def _pallas_supported(x, scale, shift) -> bool:
@@ -68,7 +73,7 @@ def _bwd(eps, interpret, res, dy):
     )
     dscale, dshift = adaln_bwd_dmod_pallas(
         dy, x, mu, rstd,
-        d_block=_divisor_block(d, DEFAULT_D_BLOCK),
+        d_block=_divisor_block(d, DEFAULT_D_BLOCK, granule=128),
         seq_block=_divisor_block(s, DEFAULT_DMOD_SEQ_BLOCK),
         interpret=interpret,
     )
@@ -81,9 +86,16 @@ _adaln_pallas.defvjp(_fwd, _bwd)
 def adaln_modulate(x, scale, shift, *, eps: float = 1e-6, interpret: bool = False):
     """Fused LayerNorm + Modulate.  x: [B, S, D]; scale/shift: [B, D].
 
-    Falls back to the fused jnp reference when the shape is outside the
-    kernel's tiling constraints (non-128-multiple D).
+    A shape outside the kernel's tiling constraints (D not a multiple of
+    128, or no sequence tile of >= 8 rows dividing S) raises when compiled
+    and runs the fused jnp reference in interpret mode.
     """
     if not _pallas_supported(x, scale, shift):
+        kernel_fallback(
+            f"fused AdaLN needs x [B, S, D] with D % 128 == 0 and a sequence "
+            f"tile of >= 8 rows dividing S (got x {x.shape}, scale "
+            f"{scale.shape})",
+            interpret=interpret,
+        )
         return adaln_fused_ref(x, scale, shift, eps)
     return _adaln_pallas(x, scale, shift, eps, interpret)

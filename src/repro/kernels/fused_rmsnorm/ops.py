@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..fallback import kernel_fallback
 from .ref import gated_rms_norm_fused_ref, rms_norm_fused_ref
 from .rmsnorm import (
     DEFAULT_D_BLOCK,
@@ -25,8 +26,17 @@ def _blk(n: int, target: int) -> int:
     return b if n % b == 0 else n
 
 
-def _supported(x) -> bool:
-    return x.shape[-1] % 128 == 0 and (x.size // x.shape[-1]) % 8 == 0
+def _supported(x, interpret: bool) -> bool:
+    """Whether the kernels tile ``x`` (D % 128, rows % 8).  An untileable
+    shape raises when compiled and warns in interpret mode."""
+    if x.shape[-1] % 128 == 0 and (x.size // x.shape[-1]) % 8 == 0:
+        return True
+    kernel_fallback(
+        f"fused RMSNorm needs D % 128 == 0 and a row count divisible by 8 "
+        f"(got {x.shape})",
+        interpret=interpret,
+    )
+    return False
 
 
 # -- plain rmsnorm -------------------------------------------------------------
@@ -65,7 +75,7 @@ _rms_pallas.defvjp(_rms_fwd, _rms_bwd)
 
 
 def rms_norm(x, w, *, eps: float = 1e-6, interpret: bool = False):
-    if not _supported(x):
+    if not _supported(x, interpret):
         return rms_norm_fused_ref(x, w, eps)
     shape = x.shape
     y = _rms_pallas(x.reshape(-1, shape[-1]), w, eps, interpret)
@@ -117,7 +127,7 @@ _grms_pallas.defvjp(_grms_fwd, _grms_bwd)
 
 
 def gated_rms_norm(x, w, gate, *, eps: float = 1e-6, interpret: bool = False):
-    if not _supported(x):
+    if not _supported(x, interpret):
         return gated_rms_norm_fused_ref(x, w, gate, eps)
     shape = x.shape
     y = _grms_pallas(

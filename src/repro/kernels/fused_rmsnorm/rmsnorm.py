@@ -23,40 +23,51 @@ DEFAULT_ROW_BLOCK = 256
 DEFAULT_D_BLOCK = 512
 
 
+# Block layout: the weight rides as a [1, D] row and rstd as an [N, 1]
+# column.  1-D blocks are refused by the TPU compiler (XLA and Mosaic tile a
+# 1-D f32 array differently), and the lane reduction leaves rstd
+# sublane-major, so the column stores with no relayout.
+
+
+def _rows(rb, d):
+    return pl.BlockSpec((rb, d), lambda i: (i, 0))
+
+
+def _weight(d):
+    return pl.BlockSpec((1, d), lambda i: (0, 0))
+
+
+def _col(rb):
+    return pl.BlockSpec((rb, 1), lambda i: (i, 0))
+
+
 # -- forward -----------------------------------------------------------------
 
 
 def _fwd_kernel(x_ref, w_ref, y_ref, rstd_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
     rstd = jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps)
-    y_ref[...] = (x * rstd * w_ref[...].astype(jnp.float32)[None, :]).astype(
-        y_ref.dtype
-    )
-    rstd_ref[...] = rstd[:, 0]
+    y_ref[...] = (x * rstd * w_ref[...].astype(jnp.float32)).astype(y_ref.dtype)
+    rstd_ref[...] = rstd
 
 
 def rms_fwd_pallas(x2d, w, *, eps: float, row_block: int, interpret: bool):
+    """Returns (y [N, D], rstd [N] fp32)."""
     n, d = x2d.shape
     rb = min(row_block, n)
     assert n % rb == 0 and d % 128 == 0
     y, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=(n // rb,),
-        in_specs=[
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((rb,), lambda i: (i,)),
-        ],
+        in_specs=[_rows(rb, d), _weight(d)],
+        out_specs=[_rows(rb, d), _col(rb)],
         out_shape=[
             jax.ShapeDtypeStruct((n, d), x2d.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x2d, w)
-    return y, rstd
+    )(x2d, w[None, :])
+    return y, rstd[:, 0]
 
 
 def _gated_fwd_kernel(x_ref, w_ref, g_ref, y_ref, rstd_ref, *, eps):
@@ -64,10 +75,10 @@ def _gated_fwd_kernel(x_ref, w_ref, g_ref, y_ref, rstd_ref, *, eps):
     g = g_ref[...].astype(jnp.float32)
     rstd = jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps)
     silu = g * jax.nn.sigmoid(g)
-    y_ref[...] = (x * rstd * w_ref[...].astype(jnp.float32)[None, :] * silu).astype(
+    y_ref[...] = (x * rstd * w_ref[...].astype(jnp.float32) * silu).astype(
         y_ref.dtype
     )
-    rstd_ref[...] = rstd[:, 0]
+    rstd_ref[...] = rstd
 
 
 def gated_rms_fwd_pallas(x2d, w, g2d, *, eps: float, row_block: int, interpret: bool):
@@ -77,22 +88,15 @@ def gated_rms_fwd_pallas(x2d, w, g2d, *, eps: float, row_block: int, interpret: 
     y, rstd = pl.pallas_call(
         functools.partial(_gated_fwd_kernel, eps=eps),
         grid=(n // rb,),
-        in_specs=[
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((rb,), lambda i: (i,)),
-        ],
+        in_specs=[_rows(rb, d), _weight(d), _rows(rb, d)],
+        out_specs=[_rows(rb, d), _col(rb)],
         out_shape=[
             jax.ShapeDtypeStruct((n, d), x2d.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x2d, w, g2d)
-    return y, rstd
+    )(x2d, w[None, :], g2d)
+    return y, rstd[:, 0]
 
 
 # -- backward: dx (rowwise) ----------------------------------------------------
@@ -101,9 +105,9 @@ def gated_rms_fwd_pallas(x2d, w, g2d, *, eps: float, row_block: int, interpret: 
 def _bwd_dx_kernel(dy_ref, x_ref, w_ref, rstd_ref, dx_ref):
     dy = dy_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
-    rstd = rstd_ref[...][:, None]
+    rstd = rstd_ref[...]  # [rb, 1]
     x_hat = x * rstd
-    dxhat = dy * w_ref[...].astype(jnp.float32)[None, :]
+    dxhat = dy * w_ref[...].astype(jnp.float32)
     m = (dxhat * x_hat).mean(axis=-1, keepdims=True)
     dx_ref[...] = (rstd * (dxhat - x_hat * m)).astype(dx_ref.dtype)
 
@@ -114,16 +118,11 @@ def rms_bwd_dx_pallas(dy, x2d, w, rstd, *, row_block: int, interpret: bool):
     return pl.pallas_call(
         _bwd_dx_kernel,
         grid=(n // rb,),
-        in_specs=[
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((rb, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((rb,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
+        in_specs=[_rows(rb, d), _rows(rb, d), _weight(d), _col(rb)],
+        out_specs=_rows(rb, d),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
         interpret=interpret,
-    )(dy, x2d, w, rstd)
+    )(dy, x2d, w[None, :], rstd[:, None])
 
 
 # -- backward: dw via D-tile coalesced reduction -------------------------------
@@ -137,8 +136,8 @@ def _bwd_dw_kernel(dy_ref, x_ref, rstd_ref, dw_ref):
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
     dy = dy_ref[...].astype(jnp.float32)  # [rb, db]
-    x_hat = x_ref[...].astype(jnp.float32) * rstd_ref[...][:, None]
-    dw_ref[0, :] += (dy * x_hat).sum(axis=0)
+    x_hat = x_ref[...].astype(jnp.float32) * rstd_ref[...]
+    dw_ref[...] += (dy * x_hat).sum(axis=0, keepdims=True)
 
 
 def rms_bwd_dw_pallas(dy, x2d, rstd, *, d_block: int, row_block: int, interpret: bool):
@@ -146,16 +145,13 @@ def rms_bwd_dw_pallas(dy, x2d, rstd, *, d_block: int, row_block: int, interpret:
     db = min(d_block, d)
     rb = min(row_block, n)
     assert n % rb == 0 and d % db == 0
+    tile = pl.BlockSpec((rb, db), lambda j, k: (k, j))
     (dw,) = pl.pallas_call(
         _bwd_dw_kernel,
         grid=(d // db, n // rb),  # rows innermost -> VMEM accumulation
-        in_specs=[
-            pl.BlockSpec((rb, db), lambda j, k: (k, j)),
-            pl.BlockSpec((rb, db), lambda j, k: (k, j)),
-            pl.BlockSpec((rb,), lambda j, k: (k,)),
-        ],
+        in_specs=[tile, tile, pl.BlockSpec((rb, 1), lambda j, k: (k, 0))],
         out_specs=[pl.BlockSpec((1, db), lambda j, k: (0, j))],
         out_shape=[jax.ShapeDtypeStruct((1, d), jnp.float32)],
         interpret=interpret,
-    )(dy, x2d, rstd)
+    )(dy, x2d, rstd[:, None])
     return dw[0]

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs.registry import get_config, get_smoke_config
 from repro.core.cost_model import CostModel
+from repro.launch.cache import enable_compilation_cache
 from repro.models import mmdit as M
 from repro.models import transformer as T
 from repro.serve import DiffusionServeEngine, ServeConfig, ServeEngine
@@ -119,6 +120,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compilation_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "mmdit":
         serve_mmdit(cfg, args)

@@ -13,6 +13,9 @@ trains the remaining steps.  A killed-and-resumed run therefore emits
 byte-identical plan digests and matching parameters versus the
 uninterrupted run; ``--digest-log`` appends each consumed plan's digest to
 a file so CI can ``cmp`` the two streams.
+
+:func:`train` is the path after config resolution (loader -> ``Trainer``
+-> checkpoint); ``chip_smoke.py`` drives it at chip-scale shapes.
 """
 
 from __future__ import annotations
@@ -40,9 +43,18 @@ from repro.optim.adamw import OptimizerConfig
 from repro.train.loop import Trainer, deserialize_rng_key
 from repro.train.steps import init_state
 from repro.checkpoint import store
+from repro.launch.cache import enable_compilation_cache
+
+#: the launcher's bucketed stream at CPU scale: seq lens stay <= 512 so LM
+#: archs fit a single softmax-xent chunk
+CPU_SHAPES = (
+    DataShape(1, 256, 256, 16),
+    DataShape(9, 192, 192, 16),
+    DataShape(17, 192, 192, 16),
+)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -105,6 +117,11 @@ def main() -> None:
                     help="poll this path each step; its appearance (or "
                          "SIGTERM) triggers a graceful preemption: full "
                          "run-state save, then clean exit")
+    return ap
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
     if args.workers > 1 and not args.adaptive:
         ap.error("--workers > 1 requires --adaptive (the fixed-shape stream "
@@ -133,14 +150,36 @@ def main() -> None:
         ap.error("--sp-max-ranks > 1 needs the planner-driven multi-rank "
                  "stream (--workers N > 1, usually with --mesh)")
 
+    enable_compilation_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    train(args, cfg, CPU_SHAPES)
+
+
+def bucketing_policy(batch: int) -> BucketingPolicy:
+    """The dual constraint for ``--batch``: ``batch * 1024`` tokens of
+    memory, ``2e7`` of ``B * S^2`` compute."""
+    return BucketingPolicy(m_mem=batch * 1024, m_comp=2.0e7, p=2.0)
+
+
+def optimizer_for(args: argparse.Namespace, cfg) -> OptimizerConfig:
+    """The run's AdamW: the arch's peak LR, held constant over --steps."""
     opt = get_optimizer(args.arch)
-    opt = OptimizerConfig(
+    return OptimizerConfig(
         peak_lr=opt.peak_lr, schedule="constant", warmup=0,
         total_steps=args.steps, state_dtype=cfg.opt_state_dtype,
     )
 
-    state = init_state(jax.random.PRNGKey(0), cfg, opt)
+
+def train(args: argparse.Namespace, cfg, shapes):
+    """Train ``cfg`` for the run ``args`` (parsed by :func:`build_parser`)
+    describes; with ``--adaptive`` the stream draws the media ``shapes``
+    under :func:`bucketing_policy`.  Returns ``(state, history)``, or
+    ``None`` when a resumed checkpoint already covers ``--steps``."""
+    opt = optimizer_for(args, cfg)
+
+    # the initial state lives on the host: the engine places its own device
+    # copy, so the device never holds two copies of the state
+    state = jax.device_get(init_state(jax.random.PRNGKey(0), cfg, opt))
     start = 0
     run_state = None
     if args.resume:
@@ -156,16 +195,13 @@ def main() -> None:
     if n_run <= 0:
         print(f"nothing to do: checkpoint already at step {start} "
               f">= --steps {args.steps}")
-        return
+        return None
 
     rng = np.random.default_rng(0)
 
     if args.adaptive:
-        # variable-shape bucketed stream with the dual constraint; seq lens
-        # stay <= 512 so LM archs fit a single softmax-xent chunk
-        shapes = [DataShape(1, 256, 256, 16), DataShape(9, 192, 192, 16),
-                  DataShape(17, 192, 192, 16)]
-        policy = BucketingPolicy(m_mem=args.batch * 1024, m_comp=2.0e7, p=2.0)
+        # variable-shape bucketed stream with the dual constraint
+        policy = bucketing_policy(args.batch)
         buckets = policy.make_buckets(shapes)
     else:
         buckets = None
@@ -277,7 +313,7 @@ def main() -> None:
             f"resume with --resume to train the remaining "
             f"{args.steps - start - n_done} steps"
         )
-        return
+        return state, hist
     print(
         f"done: {n_run} steps ({start}..{args.steps - 1}), "
         f"final loss {hist.losses[-1]:.4f}, "
@@ -287,6 +323,7 @@ def main() -> None:
                run_state=trainer.last_run_state)
     print(f"checkpoint (weights + run state) at step {args.steps} -> "
           f"{Path(args.ckpt_dir)}")
+    return state, hist
 
 
 if __name__ == "__main__":

@@ -42,8 +42,8 @@ from repro.core.dispatch import SplitShard, merge_split_worker_steps
 from repro.core.telemetry import WorkerStepRecord
 from repro.distributed.plan_exec import PlanExecutor, worker_steps_digest
 from repro.models.config import ModelConfig
-from repro.optim.adamw import OptimizerConfig, adamw_update
-from repro.train.steps import make_pool_grad_step
+from repro.optim.adamw import OptimizerConfig
+from repro.train.steps import make_pool_grad_step, make_pool_update
 
 WorkerSteps = Sequence[Sequence[tuple[Any, dict]]]  # [rank][(bucket, batch)]
 
@@ -133,20 +133,8 @@ class EmulatedEngine(ExecutionEngine):
             lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=(0,)
         )
 
-        def update(state, acc, loss_sum, n):
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) / n, acc)
-            new_params, new_opt, stats = adamw_update(
-                state["params"], grads, state["opt"], state["step"], opt
-            )
-            new_state = {
-                "params": new_params,
-                "opt": new_opt,
-                "step": state["step"] + 1,
-            }
-            return new_state, {"loss": loss_sum / n, **stats}
-
         self._update = jax.jit(
-            update, donate_argnums=(0,) if donate else ()
+            make_pool_update(opt), donate_argnums=(0,) if donate else ()
         )
         self._seen_signatures: set = set()
         self._records: list[WorkerStepRecord] = []

@@ -98,6 +98,26 @@ def make_pool_grad_step(cfg: ModelConfig, policy=None) -> Callable:
     return grad_step
 
 
+def make_pool_update(opt: OptimizerConfig) -> Callable:
+    """The optimizer update after a pool's gradients are summed: AdamW on
+    the pool-mean gradient (fp32) and the pool-mean loss.  Shared by
+    ``EmulatedEngine`` (jitted, state donated) and ``oracle_step``."""
+
+    def update(state, grad_sum, loss_sum, n):
+        grads = jax.tree.map(lambda g: g.astype(jnp.float32) / n, grad_sum)
+        new_params, new_opt, stats = adamw_update(
+            state["params"], grads, state["opt"], state["step"], opt
+        )
+        new_state = {
+            "params": new_params,
+            "opt": new_opt,
+            "step": state["step"] + 1,
+        }
+        return new_state, {"loss": loss_sum / n, **stats}
+
+    return update
+
+
 def make_sp_loss_fn(cfg: ModelConfig, policy=None, *, seq_axis: str = "seq",
                     unroll: bool = False) -> Callable:
     """Per-shard loss for a sequence-parallel split microbatch.

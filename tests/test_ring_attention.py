@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.flash_attention.flash import flash_attention_fwd_pallas
@@ -61,12 +60,12 @@ def _run_case(kranks, s, lengths, causal, dt, *, pallas: bool):
             return ring_attention_ref(
                 q_, k_, v_, qs, kvs, axis_name="seq", causal=causal
             )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         ring_fn,
         mesh=mesh,
         in_specs=(P(None, None, "seq", None),) * 3 + (P(None, "seq"),) * 2,
         out_specs=P(None, None, "seq", None),
-        check_rep=False,
+        check_vma=False,
     )
 
     out_ring = sharded(q, k, v, seg, seg)
@@ -134,14 +133,14 @@ class TestRingAxisSize:
         k = jax.random.normal(ks[1], (1, 1, s, 128), jnp.float32)
         v = jax.random.normal(ks[2], (1, 1, s, 128), jnp.float32)
         mesh = Mesh(np.array(jax.devices()[:1]), ("seq",))
-        out = shard_map(
+        out = jax.shard_map(
             lambda q_, k_, v_, a, b_: ring_flash_attention(
                 q_, k_, v_, a, b_, axis_name="seq", causal=True, interpret=True
             ),
             mesh=mesh,
             in_specs=(P(None, None, "seq", None),) * 3 + (P(None, "seq"),) * 2,
             out_specs=P(None, None, "seq", None),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v, seg, seg)
         ref = flash_attention_fwd_pallas(
             q, k, v, seg, seg, causal=True, interpret=True
