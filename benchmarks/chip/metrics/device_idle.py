@@ -1,0 +1,9 @@
+"""device_idle: share of the traced window in which no operation ran on a
+device, mean over the cell's devices."""
+
+
+def read(run: dict) -> float | None:
+    trace = run["trace"]
+    if trace is None or trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - trace.busy_s / trace.window_s)
