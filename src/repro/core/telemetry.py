@@ -4,13 +4,13 @@ The paper: "it monitors the waiting time wait_sync of each GPU in real-time,
 identifies the primary bottleneck using bottleneck analysis tools, and
 dynamically recalibrates bucket configurations."
 
-``TelemetryBuffer`` accumulates per-step, per-worker records (compute time,
-data-wait, barrier-wait) and exposes:
+``TelemetryBuffer`` accumulates per-step, per-worker compute times and
+exposes:
 
 * cost-model training pairs ``(B, S, t)``,
 * per-worker health (persistent-straggler detection),
-* a bottleneck verdict: compute-imbalance vs data-starvation vs
-  communication-bound.
+* a bottleneck verdict: compute imbalance (barrier wait, the gap between a
+  step's slowest worker and each other) or balanced.
 """
 
 from __future__ import annotations
@@ -31,8 +31,6 @@ class WorkerStepRecord:
     batch_size: int
     seq_len: int
     compute_time: float
-    data_wait: float = 0.0
-    comm_time: float = 0.0
     # provenance of ``compute_time``: "host" = the host clock bracketed a
     # blocking dispatch (serial measured mode — honest but it serializes
     # ranks); "device" = consecutive device-completion timestamps observed
@@ -49,17 +47,11 @@ class WorkerStepRecord:
     # ``CostModel.fit_comm_scale``.
     ring_ranks: int = 1
 
-    @property
-    def total(self) -> float:
-        return self.compute_time + self.data_wait + self.comm_time
-
 
 @dataclasses.dataclass(frozen=True)
 class BottleneckReport:
-    verdict: str  # 'compute_imbalance' | 'data_starvation' | 'communication' | 'balanced'
+    verdict: str  # 'compute_imbalance' | 'balanced'
     mean_wait_sync: float
-    mean_data_wait: float
-    mean_comm: float
     mean_compute: float
     detail: str
 
@@ -71,7 +63,7 @@ class TelemetryBuffer:
 
     def add(self, rec: WorkerStepRecord) -> None:
         self._records.append(rec)
-        self._step_times.setdefault(rec.step, []).append(rec.total)
+        self._step_times.setdefault(rec.step, []).append(rec.compute_time)
         # keep the per-step index bounded like the deque
         if len(self._step_times) > 8192:
             for k in sorted(self._step_times)[:1024]:
@@ -110,13 +102,6 @@ class TelemetryBuffer:
                 BenchSample(r.batch_size, r.seq_len, r.compute_time)
             )
         return out
-
-    def wait_sync(self, step: int) -> list[float]:
-        ts = self._step_times.get(step, [])
-        if not ts:
-            return []
-        m = max(ts)
-        return [m - t for t in ts]
 
     def straggler_workers(
         self, *, window: int = 64, threshold: float = 1.25
@@ -207,25 +192,18 @@ class TelemetryBuffer:
     def bottleneck(self) -> BottleneckReport:
         recs = list(self._records)
         if not recs:
-            return BottleneckReport("balanced", 0, 0, 0, 0, "no data")
-        data_wait = float(np.mean([r.data_wait for r in recs]))
-        comm = float(np.mean([r.comm_time for r in recs]))
+            return BottleneckReport("balanced", 0, 0, "no data")
         compute = float(np.mean([r.compute_time for r in recs]))
         waits = []
         for s in self._step_times.values():
             m = max(s)
             waits.extend(m - t for t in s)
         wait_sync = float(np.mean(waits)) if waits else 0.0
-        total = max(compute + data_wait + comm, 1e-12)
-        if data_wait > 0.25 * total:
-            verdict, detail = "data_starvation", "data pipeline slower than step"
-        elif comm > 0.4 * total:
-            verdict, detail = "communication", "collectives dominate step time"
-        elif wait_sync > 0.15 * compute:
+        if wait_sync > 0.15 * compute:
             verdict, detail = (
                 "compute_imbalance",
                 "barrier wait >15% of compute: bucket loads are uneven",
             )
         else:
             verdict, detail = "balanced", "no dominant bottleneck"
-        return BottleneckReport(verdict, wait_sync, data_wait, comm, compute, detail)
+        return BottleneckReport(verdict, wait_sync, compute, detail)

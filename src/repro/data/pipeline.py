@@ -16,7 +16,8 @@ A background prefetch thread keeps ``prefetch`` steps of synthetic batches
 ready so device steps never wait on the host (the paper's shape benchmark
 explicitly excludes data-loading jitter; this is how the real loop does
 too).  ``plan_update()`` lets the closed-loop scheduler swap bucket tables
-mid-training without draining the pipeline.
+mid-training without draining the pipeline.  The consumer's wait for the
+next step is a ``loader.wait`` profiler span.
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ import threading
 from collections import deque
 from typing import Callable, Deque, Iterator, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.bucketing import Bucket
@@ -127,15 +129,16 @@ class BucketedLoader:
         return self
 
     def __next__(self) -> list[tuple[Bucket, dict]]:
-        while True:
-            if self._error is not None:
-                raise RuntimeError("loader producer failed") from self._error
-            try:
-                return self._q.get(timeout=0.5)
-            except queue.Empty:
-                if self._stop.is_set():  # closed: end the stream
-                    raise StopIteration
-                continue
+        with jax.profiler.TraceAnnotation("loader.wait"):
+            while True:
+                if self._error is not None:
+                    raise RuntimeError("loader producer failed") from self._error
+                try:
+                    return self._q.get(timeout=0.5)
+                except queue.Empty:
+                    if self._stop.is_set():  # closed: end the stream
+                        raise StopIteration
+                    continue
 
     def close(self) -> None:
         self._stop.set()
@@ -669,7 +672,7 @@ class ShardedBucketedLoader:
 
         The step is popped atomically under the lock, so an elastic resize
         can never interleave with a half-consumed step."""
-        with self._cv:
+        with jax.profiler.TraceAnnotation("loader.wait"), self._cv:
             while True:
                 self._check_error()
                 n = len(self._pending)

@@ -666,7 +666,7 @@ class PlanExecutor:
           ``out["timers"].join()`` yields the same ``WorkerStepRecord``
           stream with ``timing="device"``.
         * ``measure="serial"`` — block per microbatch for host-clock
-          telemetry.  Honest per-(B, S) samples, but ranks run one after
+          telemetry, each block an ``engine.sync`` profiler span.  Honest per-(B, S) samples, but ranks run one after
           another: wall-clock degenerates to the cross-rank SUM.  Kept as
           the benchmark baseline; opt in explicitly.
 
@@ -756,7 +756,8 @@ class PlanExecutor:
                         loss = self._device_view(loss_g, dev)
                         grads = self._device_view(grads_g, dev)
                         if measure == "serial":
-                            loss.block_until_ready()
+                            with jax.profiler.TraceAnnotation("engine.sync"):
+                                loss.block_until_ready()
                             dt = time.perf_counter() - t0
                             g["dt"] = dt
                             if not fresh:
@@ -830,7 +831,8 @@ class PlanExecutor:
                 t0 = time.perf_counter()
                 loss, grads = self._grad_step(params_r, batch_r, key_r, idx_r)
                 if measure == "serial":
-                    loss.block_until_ready()
+                    with jax.profiler.TraceAnnotation("engine.sync"):
+                        loss.block_until_ready()
                     dt = time.perf_counter() - t0
                     if not fresh:  # compile executions poison telemetry
                         scale = time_scale(rank) if time_scale else 1.0

@@ -229,6 +229,7 @@ def flash_attention_fwd_pallas(
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, dh), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(*operands)
     return out, lse[..., 0]
@@ -350,6 +351,7 @@ def flash_attention_bwd_dq_pallas(
         out_specs=q_tile,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((qb, dh), jnp.float32)],
+        name="flash_dq",
         interpret=interpret,
     )(*operands)
 
@@ -456,6 +458,7 @@ def flash_attention_bwd_dkv_pallas(
             pltpu.VMEM((kb, dh), jnp.float32),
             pltpu.VMEM((kb, dh), jnp.float32),
         ],
+        name="flash_dkv",
         interpret=interpret,
     )(*operands)
     return dk, dv

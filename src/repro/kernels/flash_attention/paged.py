@@ -162,6 +162,7 @@ def paged_attention_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),

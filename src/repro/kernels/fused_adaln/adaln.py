@@ -89,6 +89,7 @@ def adaln_fwd_pallas(x, scale, shift, *, eps: float, seq_block: int, interpret: 
             jax.ShapeDtypeStruct((b, s, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, s, 1), jnp.float32),
         ],
+        name="adaln_fwd",
         interpret=interpret,
     )(x, scale[:, None, :], shift[:, None, :])
     return y, mu[..., 0], rstd[..., 0]
@@ -125,6 +126,7 @@ def adaln_bwd_dx_pallas(dy, x, mu, rstd, scale, *, seq_block: int, interpret: bo
         ],
         out_specs=_row_spec(sb, d),
         out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
+        name="adaln_dx",
         interpret=interpret,
     )(dy, x, mu[..., None], rstd[..., None], scale[:, None, :])
 
@@ -169,6 +171,7 @@ def adaln_bwd_dmod_pallas(
             jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
         ],
+        name="adaln_dmod",
         interpret=interpret,
     )(dy, x, mu[..., None], rstd[..., None])
     return dscale[:, 0], dshift[:, 0]
@@ -202,6 +205,7 @@ def adaln_bwd_dmod_naive_pallas(dy, x, mu, rstd, *, interpret: bool):
             jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
         ],
+        name="adaln_dmod_naive",
         interpret=interpret,
     )(dy, x, mu[..., None], rstd[..., None])
     return dscale[:, 0], dshift[:, 0]

@@ -65,6 +65,7 @@ def rms_fwd_pallas(x2d, w, *, eps: float, row_block: int, interpret: bool):
             jax.ShapeDtypeStruct((n, d), x2d.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        name="rmsnorm_fwd",
         interpret=interpret,
     )(x2d, w[None, :])
     return y, rstd[:, 0]
@@ -94,6 +95,7 @@ def gated_rms_fwd_pallas(x2d, w, g2d, *, eps: float, row_block: int, interpret: 
             jax.ShapeDtypeStruct((n, d), x2d.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        name="rmsnorm_gated_fwd",
         interpret=interpret,
     )(x2d, w[None, :], g2d)
     return y, rstd[:, 0]
@@ -121,6 +123,7 @@ def rms_bwd_dx_pallas(dy, x2d, w, rstd, *, row_block: int, interpret: bool):
         in_specs=[_rows(rb, d), _rows(rb, d), _weight(d), _col(rb)],
         out_specs=_rows(rb, d),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
+        name="rmsnorm_dx",
         interpret=interpret,
     )(dy, x2d, w[None, :], rstd[:, None])
 
@@ -152,6 +155,7 @@ def rms_bwd_dw_pallas(dy, x2d, rstd, *, d_block: int, row_block: int, interpret:
         in_specs=[tile, tile, pl.BlockSpec((rb, 1), lambda j, k: (k, 0))],
         out_specs=[pl.BlockSpec((1, db), lambda j, k: (0, j))],
         out_shape=[jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        name="rmsnorm_dw",
         interpret=interpret,
     )(dy, x2d, rstd[:, None])
     return dw[0]

@@ -9,7 +9,9 @@ Block layout (Wan 2.1 / DiT-with-cross-attn, AdaLN conditioning):
 
 ``adaln_modulate`` routes through ``repro.kernels`` — the fused
 LayerNorm-Modulate op that is the paper's second contribution.  QK-Norm is
-the fused q/k RMSNorm (paper §4.4).
+the fused q/k RMSNorm (paper §4.4).  Each sub-layer runs under a
+``jax.named_scope`` (``adaln``, ``self_attn``, ``cross_attn``, ``mlp``), so a
+profiler trace names the device ops of each.
 
 Training objective: rectified flow (x_t = (1-t) x0 + t eps, predict v = eps - x0),
 matching Wan 2.1's flow-matching setup.
@@ -103,39 +105,44 @@ def _block(bp: Params, x, txt, mod, cfg: ModelConfig, policy=None,
     shift2, scale2, gate2 = m[:, 3], m[:, 4], m[:, 5]
 
     # --- self attention with fused AdaLN-modulate
-    hmod = K.adaln_modulate(x, scale1, shift1)
-    qkv = hmod @ bp["wqkv"]
-    q = qkv[..., : h * dh].reshape(b, s, h, dh)
-    k = qkv[..., h * dh : 2 * h * dh].reshape(b, s, h, dh)
-    v = qkv[..., 2 * h * dh :].reshape(b, s, h, dh)
-    q, k = K.qk_norm(q, k, bp["qnorm"], bp["knorm"])
-    if policy is not None:
-        q = policy.constrain(q, "attn_q")
-        k = policy.constrain(k, "attn_kv")
-        v = policy.constrain(v, "attn_kv")
-    ctx = K.attention(  # full bidirectional; flash kernel on TPU backends
-        q, k, v, causal=False,
-        q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
-    )
-    x = x + gate1[:, None, :].astype(x.dtype) * (ctx.reshape(b, s, h * dh) @ bp["wo"])
+    with jax.named_scope("adaln"):
+        hmod = K.adaln_modulate(x, scale1, shift1)
+    with jax.named_scope("self_attn"):
+        qkv = hmod @ bp["wqkv"]
+        q = qkv[..., : h * dh].reshape(b, s, h, dh)
+        k = qkv[..., h * dh : 2 * h * dh].reshape(b, s, h, dh)
+        v = qkv[..., 2 * h * dh :].reshape(b, s, h, dh)
+        q, k = K.qk_norm(q, k, bp["qnorm"], bp["knorm"])
+        if policy is not None:
+            q = policy.constrain(q, "attn_q")
+            k = policy.constrain(k, "attn_kv")
+            v = policy.constrain(v, "attn_kv")
+        ctx = K.attention(  # full bidirectional; flash kernel on TPU backends
+            q, k, v, causal=False,
+            q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
+        )
+        x = x + gate1[:, None, :].astype(x.dtype) * (ctx.reshape(b, s, h * dh) @ bp["wo"])
 
     # --- cross attention to text (segment-scoped for packed windows)
-    hn = apply_norm(bp["norm3"], x, "layernorm", cfg.norm_eps)
-    qx = (hn @ bp["xq"]).reshape(b, s, h, dh)
-    n = txt.shape[1]
-    kvx = txt @ bp["xkv"]
-    kx = kvx[..., : h * dh].reshape(b, n, h, dh)
-    vx = kvx[..., h * dh :].reshape(b, n, h, dh)
-    ctx2 = K.attention(
-        qx, kx, vx, causal=False,
-        q_segment_ids=segment_ids if text_segment_ids is not None else None,
-        kv_segment_ids=text_segment_ids,
-    )
-    x = x + ctx2.reshape(b, s, h * dh) @ bp["xo"]
+    with jax.named_scope("cross_attn"):
+        hn = apply_norm(bp["norm3"], x, "layernorm", cfg.norm_eps)
+        qx = (hn @ bp["xq"]).reshape(b, s, h, dh)
+        n = txt.shape[1]
+        kvx = txt @ bp["xkv"]
+        kx = kvx[..., : h * dh].reshape(b, n, h, dh)
+        vx = kvx[..., h * dh :].reshape(b, n, h, dh)
+        ctx2 = K.attention(
+            qx, kx, vx, causal=False,
+            q_segment_ids=segment_ids if text_segment_ids is not None else None,
+            kv_segment_ids=text_segment_ids,
+        )
+        x = x + ctx2.reshape(b, s, h * dh) @ bp["xo"]
 
     # --- MLP with fused AdaLN-modulate
-    hmod2 = K.adaln_modulate(x, scale2, shift2)
-    x = x + gate2[:, None, :].astype(x.dtype) * apply_mlp(bp["mlp"], hmod2)
+    with jax.named_scope("adaln"):
+        hmod2 = K.adaln_modulate(x, scale2, shift2)
+    with jax.named_scope("mlp"):
+        x = x + gate2[:, None, :].astype(x.dtype) * apply_mlp(bp["mlp"], hmod2)
     return x
 
 
@@ -173,7 +180,8 @@ def forward(
     x, _ = jax.lax.scan(body, x, params["blocks"], unroll=unroll)
 
     fm = (temb @ params["final_mod"]).reshape(-1, 2, cfg.d_model).astype(jnp.float32)
-    x = K.adaln_modulate(x, fm[:, 0], fm[:, 1])
+    with jax.named_scope("adaln"):
+        x = K.adaln_modulate(x, fm[:, 0], fm[:, 1])
     return x @ params["x_out"]
 
 

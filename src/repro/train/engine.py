@@ -18,6 +18,10 @@ semantics, different telemetry, and executor internals wired through
 * :meth:`ExecutionEngine.prepare` — optional H2D double-buffer hook: stage
   step ``i+1``'s batches while step ``i`` computes.
 
+Both engines mark ``execute_step`` as an ``engine.step`` profiler span and
+every host block on the devices (a microbatch's loss, the timer join) as
+an ``engine.sync`` span, beside the trainer's ``train.*`` spans.
+
 Both engines implement the SAME gradient semantics as
 :func:`repro.distributed.plan_exec.oracle_step`: every microbatch in the
 step's global pool contributes the gradient of its own mean-token loss
@@ -158,6 +162,10 @@ class EmulatedEngine(ExecutionEngine):
         )
 
     def execute_step(self, state, worker_steps, *, step_key, step):
+        with jax.profiler.TraceAnnotation("engine.step"):
+            return self._execute_step(state, worker_steps, step_key, step)
+
+    def _execute_step(self, state, worker_steps, step_key, step):
         self._records = []
         self._last_ranks = list(range(len(worker_steps)))
         # sequence-parallel split fan-outs collapse back to their logical
@@ -196,7 +204,8 @@ class EmulatedEngine(ExecutionEngine):
                 loss, grads = self._grad_step(
                     state["params"], batch, step_key, np.int32(pool_index)
                 )
-                loss.block_until_ready()
+                with jax.profiler.TraceAnnotation("engine.sync"):
+                    loss.block_until_ready()
                 dt = time.perf_counter() - t0
                 if not fresh:  # compile executions poison telemetry
                     self._records.append(
@@ -272,7 +281,6 @@ class MeshEngine(ExecutionEngine):
         )
         self._records: list[WorkerStepRecord] = []
         self._timers = None
-        self._rank_times: list[float] | None = None
 
     def set_time_scale(self, worker: int, scale: float) -> None:
         if scale <= 0:
@@ -288,40 +296,33 @@ class MeshEngine(ExecutionEngine):
         self.executor.stage(worker_steps)
 
     def execute_step(self, state, worker_steps, *, step_key, step):
-        self._last_ranks = list(range(len(worker_steps)))
-        digests = None
-        if self._check_agreement:
-            # single-process: every rank's digest derives from the same
-            # local fan-out (multi-host deployments pass their own)
-            digest = worker_steps_digest(worker_steps)
-            digests = [digest] * self.executor.n_ranks
-        state, out = self.executor.execute(
-            state,
-            worker_steps,
-            step_key=step_key,
-            step=step,
-            digests=digests,
-            measure=self._measure,
-            time_scale=self._time_scale,
-        )
-        self._records = out.get("records", [])
-        self._timers = out.get("timers")
-        self._rank_times = out.get("rank_times")
+        with jax.profiler.TraceAnnotation("engine.step"):
+            self._last_ranks = list(range(len(worker_steps)))
+            digests = None
+            if self._check_agreement:
+                # single-process: every rank's digest derives from the same
+                # local fan-out (multi-host deployments pass their own)
+                digest = worker_steps_digest(worker_steps)
+                digests = [digest] * self.executor.n_ranks
+            state, out = self.executor.execute(
+                state,
+                worker_steps,
+                step_key=step_key,
+                step=step,
+                digests=digests,
+                measure=self._measure,
+                time_scale=self._time_scale,
+            )
+            self._records = out.get("records", [])
+            self._timers = out.get("timers")
         return state, StepOutcome(loss=out["loss"], compiled=out["compiled"])
 
     def timing_records(self) -> list[WorkerStepRecord]:
         if self._timers is not None:
-            self._records, self._rank_times = self._timers.join()
+            with jax.profiler.TraceAnnotation("engine.sync"):
+                self._records, _ = self._timers.join()
             self._timers = None
         return self._records
-
-    @property
-    def rank_times(self) -> list[float] | None:
-        """Per-rank wall times for the last measured step (after
-        :meth:`timing_records` in async mode)."""
-        if self._timers is not None:
-            self.timing_records()
-        return self._rank_times
 
 
 __all__ = [

@@ -22,6 +22,13 @@ pulled from the loader and its batches staged H2D
 (``engine.prepare``) — the double-buffer that keeps devices from waiting
 on the host.
 
+Each step runs under ``jax.profiler.StepTraceAnnotation("train.step")``;
+its host sync on the new state and loss is a ``train.sync`` span and every
+pull from the loader a ``train.fetch`` span, and the engines and loaders
+add ``engine.*`` and ``loader.*`` spans of their own.  Inside a
+``jax.profiler`` session these land in the trace on the device ops' clock;
+outside one they record nothing.
+
 The loop consumes either a single-rank stream (``BucketedLoader``: each
 item is one ``list[(bucket, batch)]``) or a planner-driven multi-rank
 stream (``ShardedBucketedLoader``: each item is per-worker lists from one
@@ -220,6 +227,11 @@ class Trainer:
             return step
         return [step]
 
+    @staticmethod
+    def _fetch(data_iter):
+        with jax.profiler.TraceAnnotation("train.fetch"):
+            return next(data_iter)
+
     def _run_state(self, next_step: int, rng, held: int) -> dict:
         """The resumable run-state blob for a checkpoint taken between
         step ``next_step - 1`` and ``next_step``."""
@@ -270,125 +282,127 @@ class Trainer:
             # cadence from there instead of re-saving on the first step
             self.ft.note_restored(start_step)
         state = engine.place_state(state)
-        item = next(data_iter) if n_steps > 0 else None
+        item = self._fetch(data_iter) if n_steps > 0 else None
         held = 0
         for i in range(n_steps):
             step_no = start_step + i
-            worker_steps = self._as_worker_steps(item)
-            t0 = time.perf_counter()
-            tok = sum(
-                bucket.tokens for ws in worker_steps for bucket, _ in ws
-            )
-            n_micro = sum(len(ws) for ws in worker_steps)
-            rng, sub = jax.random.split(rng)
-            state, out = engine.execute_step(
-                state, self._to_physical(worker_steps),
-                step_key=sub, step=step_no,
-            )
-            held = 0
-            if engine.async_dispatch and i + 1 < n_steps:
-                # devices are still computing step i: fetch step i+1 and
-                # stage its H2D transfers behind that compute
-                item = next(data_iter)
-                engine.prepare(self._to_physical(self._as_worker_steps(item)))
-                held = 1
-            recs = engine.timing_records()
-            jax.block_until_ready(state["step"])
-            dt = time.perf_counter() - t0
-            loss = float(out.loss)
-
-            hist.losses.append(loss)
-            hist.step_times.append(dt)
-            hist.tokens.append(tok)
-            if out.compiled:
-                hist.compile_steps.append(i)
-                hist.events.append(f"compile@{step_no}")
-
-            if self.scheduler is not None:
-                self.scheduler.observe(recs)
-
-            if self.chaos is not None:
-                ctx = ChaosContext(
-                    monitor=self.ft.monitor if self.ft else None,
-                    runner=self.ft,
-                    engine=engine,
-                    preemption=self.ft.preemption if self.ft else None,
+            with jax.profiler.StepTraceAnnotation("train.step", step_num=step_no):
+                worker_steps = self._as_worker_steps(item)
+                t0 = time.perf_counter()
+                tok = sum(
+                    bucket.tokens for ws in worker_steps for bucket, _ in ws
                 )
-                for msg in self.chaos.fire(step_no, ctx):
-                    hist.events.append(f"{msg}@{step_no}")
-
-            if self.ft is not None:
-                # heartbeat BEFORE failure checks: a rank that completed
-                # this step is alive, whatever the wall clock says
-                for w in engine.heartbeat_ranks():
-                    self.ft.monitor.heartbeat(w)
-                # run_state is a thunk: the snapshot work (loader rewind,
-                # RNG serialization) only happens on steps that save.
-                # ``step_no + 1`` = steps completed = the step a resume
-                # starts from; ``held`` rewinds the loader snapshot past
-                # the item the double-buffer already popped.
-                run_state = lambda: self._run_state(step_no + 1, rng, held)  # noqa: B023,E731
-                try:
-                    if self.ft.maybe_checkpoint(
-                        state, step_no + 1, dt, run_state=run_state
-                    ):
-                        hist.events.append(f"ckpt@{step_no}")
-                except SnapshotUnavailable:
-                    # a resize re-emitted the boundary plan: no replayable
-                    # snapshot THIS step.  Transient — the cadence check
-                    # re-fires next step, where a fresh draw is snapshotted
-                    hist.events.append(f"ckpt-deferred@{step_no}")
-                failure = self.ft.handle_failures(
-                    state, step_no + 1,
-                    run_state=lambda: self._failure_run_state(  # noqa: B023
-                        step_no + 1, rng, held
-                    ),
+                n_micro = sum(len(ws) for ws in worker_steps)
+                rng, sub = jax.random.split(rng)
+                state, out = engine.execute_step(
+                    state, self._to_physical(worker_steps),
+                    step_key=sub, step=step_no,
                 )
-                if failure is not None:
-                    hist.events.append(f"failure@{step_no}:{failure['plan']}")
-                try:
-                    join = self.ft.handle_joins(
-                        state, step_no + 1, run_state=run_state
+                held = 0
+                if engine.async_dispatch and i + 1 < n_steps:
+                    # devices are still computing step i: fetch step i+1 and
+                    # stage its H2D transfers behind that compute
+                    item = self._fetch(data_iter)
+                    engine.prepare(self._to_physical(self._as_worker_steps(item)))
+                    held = 1
+                recs = engine.timing_records()
+                with jax.profiler.TraceAnnotation("train.sync"):
+                    jax.block_until_ready(state["step"])
+                    dt = time.perf_counter() - t0
+                    loss = float(out.loss)
+
+                hist.losses.append(loss)
+                hist.step_times.append(dt)
+                hist.tokens.append(tok)
+                if out.compiled:
+                    hist.compile_steps.append(i)
+                    hist.events.append(f"compile@{step_no}")
+
+                if self.scheduler is not None:
+                    self.scheduler.observe(recs)
+
+                if self.chaos is not None:
+                    ctx = ChaosContext(
+                        monitor=self.ft.monitor if self.ft else None,
+                        runner=self.ft,
+                        engine=engine,
+                        preemption=self.ft.preemption if self.ft else None,
                     )
-                    if join is not None:
-                        hist.events.append(
-                            f"join@{step_no}:{join['joined']}"
-                            f"->{join['plan'].get('data_parallel')}"
+                    for msg in self.chaos.fire(step_no, ctx):
+                        hist.events.append(f"{msg}@{step_no}")
+
+                if self.ft is not None:
+                    # heartbeat BEFORE failure checks: a rank that completed
+                    # this step is alive, whatever the wall clock says
+                    for w in engine.heartbeat_ranks():
+                        self.ft.monitor.heartbeat(w)
+                    # run_state is a thunk: the snapshot work (loader rewind,
+                    # RNG serialization) only happens on steps that save.
+                    # ``step_no + 1`` = steps completed = the step a resume
+                    # starts from; ``held`` rewinds the loader snapshot past
+                    # the item the double-buffer already popped.
+                    run_state = lambda: self._run_state(step_no + 1, rng, held)  # noqa: B023,E731
+                    try:
+                        if self.ft.maybe_checkpoint(
+                            state, step_no + 1, dt, run_state=run_state
+                        ):
+                            hist.events.append(f"ckpt@{step_no}")
+                    except SnapshotUnavailable:
+                        # a resize re-emitted the boundary plan: no replayable
+                        # snapshot THIS step.  Transient — the cadence check
+                        # re-fires next step, where a fresh draw is snapshotted
+                        hist.events.append(f"ckpt-deferred@{step_no}")
+                    failure = self.ft.handle_failures(
+                        state, step_no + 1,
+                        run_state=lambda: self._failure_run_state(  # noqa: B023
+                            step_no + 1, rng, held
+                        ),
+                    )
+                    if failure is not None:
+                        hist.events.append(f"failure@{step_no}:{failure['plan']}")
+                    try:
+                        join = self.ft.handle_joins(
+                            state, step_no + 1, run_state=run_state
                         )
-                except SnapshotUnavailable:
-                    # mid-drain (a resize just re-emitted the boundary
-                    # plan): the join stays queued and is admitted at the
-                    # next snapshotable boundary
-                    hist.events.append(f"join-deferred@{step_no}")
-                preempt = self.ft.handle_preemption(
-                    state, step_no + 1,
-                    run_state=lambda: self._failure_run_state(  # noqa: B023
-                        step_no + 1, rng, held
-                    ),
-                )
-                for ev in self.ft.drain_events():
-                    hist.events.append(f"{ev}@{step_no}")
-                if preempt is not None:
-                    # grace drain complete: in-flight microbatches done,
-                    # full run state on disk — hand off cleanly
-                    hist.events.append(f"preempt@{step_no}")
-                    hist.preempted = True
-                    break
+                        if join is not None:
+                            hist.events.append(
+                                f"join@{step_no}:{join['joined']}"
+                                f"->{join['plan'].get('data_parallel')}"
+                            )
+                    except SnapshotUnavailable:
+                        # mid-drain (a resize just re-emitted the boundary
+                        # plan): the join stays queued and is admitted at the
+                        # next snapshotable boundary
+                        hist.events.append(f"join-deferred@{step_no}")
+                    preempt = self.ft.handle_preemption(
+                        state, step_no + 1,
+                        run_state=lambda: self._failure_run_state(  # noqa: B023
+                            step_no + 1, rng, held
+                        ),
+                    )
+                    for ev in self.ft.drain_events():
+                        hist.events.append(f"{ev}@{step_no}")
+                    if preempt is not None:
+                        # grace drain complete: in-flight microbatches done,
+                        # full run state on disk — hand off cleanly
+                        hist.events.append(f"preempt@{step_no}")
+                        hist.preempted = True
+                        break
 
-            if not engine.async_dispatch and i + 1 < n_steps:
-                # sync engines fetch AFTER the fault-tolerance block: the
-                # checkpoint then sits exactly on a plan boundary (nothing
-                # popped-but-unexecuted to rewind)
-                item = next(data_iter)
+                if not engine.async_dispatch and i + 1 < n_steps:
+                    # sync engines fetch AFTER the fault-tolerance block: the
+                    # checkpoint then sits exactly on a plan boundary (nothing
+                    # popped-but-unexecuted to rewind)
+                    item = self._fetch(data_iter)
 
-            if on_metrics is not None:
-                on_metrics(step_no, {"loss": loss, "time": dt, "tokens": tok})
-            if log_every and i % log_every == 0:
-                print(
-                    f"step {step_no:5d}  loss {loss:.4f}  "
-                    f"{tok/dt:,.0f} tok/s  ({n_micro} microbatches, "
-                    f"{len(worker_steps)} ranks)"
-                )
+                if on_metrics is not None:
+                    on_metrics(step_no, {"loss": loss, "time": dt, "tokens": tok})
+                if log_every and i % log_every == 0:
+                    print(
+                        f"step {step_no:5d}  loss {loss:.4f}  "
+                        f"{tok/dt:,.0f} tok/s  ({n_micro} microbatches, "
+                        f"{len(worker_steps)} ranks)"
+                    )
         # degraded variant: an end-of-run loader that cannot snapshot
         # (e.g. a resize still draining) must not crash a finished run —
         # the launcher then persists weights + trainer RNG.  A preempted
