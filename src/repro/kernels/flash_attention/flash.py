@@ -21,15 +21,15 @@ Design:
 * **GQA** without materializing repeated kv: k/v BlockSpec index maps send
   q-head h to kv-head h // group_size;
 * **tile-level skipping**: a (q_tile, kv_tile) pair is skipped entirely
-  (`pl.when`) when the causal triangle excludes it OR when the tiles'
-  segment-id ranges don't overlap.  For packed windows (contiguous,
-  non-decreasing segment ids) the range test is exact, so executed tiles —
-  and compiled FLOPs — follow Σ len_i².  ``causal=False`` is a first-class
-  mode for bidirectional DiT blocks;
+  (`pl.when`) when the causal triangle excludes it, when the tiles'
+  segment-id ranges don't overlap, OR when either tile is padding only.
+  For packed windows (contiguous, non-decreasing segment ids) the range
+  test is exact, so executed tiles — and compiled FLOPs — follow Σ len_i².
+  ``causal=False`` is a first-class mode for bidirectional DiT blocks;
 * fp32 softmax state, bf16/f32 inputs.  Segment ids are int32 ``[B, S]``;
-  ids must be non-negative — ``-1`` marks padding (padding attends only
-  padding, so real rows are exact and padded rows are sliced off by the
-  ``ops.flash_attention`` wrapper).
+  ids must be non-negative — ``-1`` marks padding, which attends nothing
+  and which nothing attends: real rows are exact, padded rows read zero
+  output and zero gradients, whatever the tiles.
 """
 
 from __future__ import annotations
@@ -47,10 +47,18 @@ DEFAULT_Q_BLOCK = 256
 DEFAULT_KV_BLOCK = 256
 
 LSE_FLOOR = 1e-37  # guards log/div on fully-masked (padding-only) rows
+# Scoped VMEM: Mosaic's default (16 MiB on a v5e) holds every kernel's
+# working set up to a 512x512 score tile; larger tiles get more of the
+# chip's 128 MiB.  The backward's four fp32 [qb, kb] temporaries dominate.
+_DEFAULT_VMEM_TILE = 512 * 512
+_LARGE_TILE_VMEM = 64 << 20
+_PAD_ROW = -2  # a padded q row's id inside the mask: equal to no kv id
 
 
 def _tile_overlap(qs_ref, ks_ref):
-    """Do the segment-id ranges of a (q_tile, kv_tile) pair intersect?
+    """Can any (q row, kv column) pair of a (q_tile, kv_tile) tile see each
+    other?  No when the tiles' segment-id ranges are disjoint, or when either
+    tile is padding only (every id ``-1``: padding attends nothing).
 
     Exact for contiguous (sorted-run) segment layouts, conservative (never
     skips a needed tile) otherwise.
@@ -59,7 +67,7 @@ def _tile_overlap(qs_ref, ks_ref):
     q_max = jnp.max(qs_ref[...])
     k_min = jnp.min(ks_ref[...])
     k_max = jnp.max(ks_ref[...])
-    return (q_min <= k_max) & (k_min <= q_max)
+    return (q_min <= k_max) & (k_min <= q_max) & (q_max >= 0) & (k_max >= 0)
 
 
 def _causal_tile_live(qi, kj, qb, kb):
@@ -77,10 +85,21 @@ def _masks(s_shape, qi, kj, causal, qs_ref, ks_ref):
         k_pos = kj * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
         mask = q_pos >= k_pos
     if qs_ref is not None:
-        # [qb, 1] column against a [1, kb] row: lane and sublane broadcasts
-        seg = qs_ref[...] == ks_ref[...]
+        # [qb, 1] column against a [1, kb] row: lane and sublane broadcasts.
+        # A padded q row's id moves below every kv id, so padding attends
+        # nothing; real rows never match a padded column's -1.
+        qs = qs_ref[...]
+        seg = jnp.where(qs >= 0, qs, _PAD_ROW) == ks_ref[...]
         mask = seg if mask is None else (mask & seg)
     return mask
+
+
+def _compiler_params(qb, kb):
+    if qb * kb <= _DEFAULT_VMEM_TILE:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_LARGE_TILE_VMEM)
 
 
 # Block layout.  The TPU compiler wants the last two dims of every block
@@ -230,6 +249,7 @@ def flash_attention_fwd_pallas(
             pltpu.VMEM((qb, dh), jnp.float32),
         ],
         name="flash_fwd",
+        compiler_params=_compiler_params(qb, kb),
         interpret=interpret,
     )(*operands)
     return out, lse[..., 0]
@@ -352,6 +372,7 @@ def flash_attention_bwd_dq_pallas(
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((qb, dh), jnp.float32)],
         name="flash_dq",
+        compiler_params=_compiler_params(qb, kb),
         interpret=interpret,
     )(*operands)
 
@@ -459,6 +480,7 @@ def flash_attention_bwd_dkv_pallas(
             pltpu.VMEM((kb, dh), jnp.float32),
         ],
         name="flash_dkv",
+        compiler_params=_compiler_params(qb, kb),
         interpret=interpret,
     )(*operands)
     return dk, dv
@@ -481,8 +503,9 @@ def attention_tile_counts(
 ) -> tuple[int, int]:
     """(executed, total) (q_tile, kv_tile) pairs per the kernels' skip rule.
 
-    Mirrors ``_causal_tile_live`` + ``_tile_overlap`` exactly; benchmarks and
-    tests use it to report the tile-skip rate without running the kernel.
+    Mirrors ``_causal_tile_live`` + ``_tile_overlap`` exactly (padding-only
+    tiles included); benchmarks and tests use it to report the tile-skip
+    rate without running the kernel.
     """
     if q_segment_ids is None:
         assert sq is not None and skv is not None
@@ -504,6 +527,7 @@ def attention_tile_counts(
                 if causal and not ((qi + 1) * qb - 1 >= kj * kb):
                     continue
                 kt = ks[bi, kj * kb : (kj + 1) * kb]
-                if qt.min() <= kt.max() and kt.min() <= qt.max():
+                overlap = qt.min() <= kt.max() and kt.min() <= qt.max()
+                if overlap and qt.max() >= 0 and kt.max() >= 0:
                     executed += 1
     return executed, total

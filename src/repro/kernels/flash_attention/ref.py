@@ -15,8 +15,8 @@ def attention_reference(
     *,
     causal: bool = True,
     scale: float | None = None,
-    q_segment_ids=None,  # [B, Sq] int; equality defines visibility
-    kv_segment_ids=None,  # [B, Skv]
+    q_segment_ids=None,  # [B, Sq] int; equal ids see each other,
+    kv_segment_ids=None,  # [B, Skv]  but padding (-1) sees nothing
 ):
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -33,7 +33,8 @@ def attention_reference(
         mask = jnp.tril(jnp.ones((sq, skv), jnp.bool_), k=skv - sq)[None, None]
     if q_segment_ids is not None:
         seg = (
-            q_segment_ids[:, None, :, None] == kv_segment_ids[:, None, None, :]
+            (q_segment_ids[:, None, :, None] == kv_segment_ids[:, None, None, :])
+            & (q_segment_ids >= 0)[:, None, :, None]
         )  # [B, 1, Sq, Skv]
         mask = seg if mask is None else (mask & seg)
     if mask is not None:

@@ -13,9 +13,9 @@ Reuses the existing segment-id machinery at two levels:
 * **shard-level skip** — a remote KV block whose per-row segment-id ranges
   don't intersect the local Q shard's is skipped outright (``lax.cond``
   around the per-step kernel call).  The predicate is the same min/max
-  range intersect as the kernel's ``_tile_overlap`` — including ``-1``
-  padding rows — so skipping is exactly as conservative as the in-kernel
-  tile skip and never changes the result.
+  range intersect as the kernel's ``_tile_overlap``, padding-only shards
+  included, so skipping is exactly as conservative as the in-kernel tile
+  skip and never changes the result.
 * **tile-level skip** — each surviving per-step call is the *existing*
   Pallas forward/backward kernel, so intra-block tiles still skip by
   segment range.
@@ -77,14 +77,15 @@ def _pick_block(s: int, default: int) -> int:
 
 def _block_overlap(q_seg, kv_seg):
     """Shard-level skip predicate: do any batch row's segment ranges
-    intersect?  Mirrors the kernel's ``_tile_overlap`` (raw min/max,
-    ``-1`` padding included) so a skipped block is one the kernel itself
-    would have masked to nothing."""
+    intersect, with neither shard padding only?  Mirrors the kernel's
+    ``_tile_overlap`` (raw min/max) so a skipped block is one the kernel
+    itself would have masked to nothing."""
     q_min = jnp.min(q_seg, axis=1)
     q_max = jnp.max(q_seg, axis=1)
     k_min = jnp.min(kv_seg, axis=1)
     k_max = jnp.max(kv_seg, axis=1)
-    return jnp.any((q_min <= k_max) & (k_min <= q_max))
+    live = (q_min <= k_max) & (k_min <= q_max) & (q_max >= 0) & (k_max >= 0)
+    return jnp.any(live)
 
 
 def _merge(state, o_t, lse_t):
@@ -135,7 +136,9 @@ def _block_ref(q, k, v, q_seg, kv_seg, *, causal, scale):
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
-    mask = (q_seg[:, None, :, None] == kv_seg[:, None, None, :])
+    mask = (q_seg[:, None, :, None] == kv_seg[:, None, None, :]) & (
+        q_seg >= 0
+    )[:, None, :, None]  # padding (-1) sees nothing
     if causal:
         sq, skv = q.shape[2], k.shape[2]
         qpos = lax.broadcasted_iota(jnp.int32, (sq, skv), 0)

@@ -50,11 +50,11 @@ def blocked_attention(
     """q: [B, Sq, H, dh], k/v: [B, Skv, H, dh] (same head count; GQA callers
     repeat kv first).  Returns [B, Sq, H, dh] in q.dtype.
 
-    Segment-id masking (equality defines visibility) is the CPU/dry-run
-    oracle for the Pallas kernel's packed-window path.  A Skv that doesn't
-    divide ``kv_block`` is padded on the KV side with masked keys — score
-    memory stays O(Sq · kv_block) for odd lengths instead of degenerating to
-    one O(Sq · Skv) block.
+    Segment-id masking (equality defines visibility; padding, -1, sees
+    nothing) is the CPU/dry-run oracle for the Pallas kernel's packed-window
+    path.  A Skv that doesn't divide ``kv_block`` is padded on the KV side
+    with masked keys — score memory stays O(Sq · kv_block) for odd lengths
+    instead of degenerating to one O(Sq · Skv) block.
     """
     if (q_segment_ids is None) != (kv_segment_ids is None):
         raise ValueError("pass both q_segment_ids and kv_segment_ids, or neither")
@@ -96,7 +96,9 @@ def blocked_attention(
             cm = (q_pos[:, None] >= k_pos[None, :])[None, None]
             mask = cm if mask is None else (mask & cm)
         if q_seg is not None:
-            sm = q_seg[:, None, :, None] == segj[:, None, None, :]
+            sm = (q_seg[:, None, :, None] == segj[:, None, None, :]) & (
+                q_seg >= 0
+            )[:, None, :, None]  # padding (-1) sees nothing
             mask = sm if mask is None else (mask & sm)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)

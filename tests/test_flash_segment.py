@@ -23,7 +23,7 @@ from hypothesis import strategies as st
 from repro.core.cost_model import packed_load
 from repro.data.packing import pack_documents, segment_id_batch, window_segment_ids
 from repro.kernels.flash_attention.flash import attention_tile_counts
-from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.flash_attention.ops import choose_tiles, flash_attention
 from repro.kernels.flash_attention.ref import attention_reference
 from repro.models.attention import (
     blocked_attention,
@@ -177,6 +177,95 @@ def test_tile_skip_matches_kernel_output():
     assert (executed, total) == (2, 4)
 
 
+def test_padding_only_tiles_skipped():
+    """Padding on both sides: tiles that hold only padding rows or only
+    padding columns are skipped (and counted so), real rows stay exact and
+    padded rows read zero output and zero gradients."""
+    s, real = 512, 200
+    ids = np.full((1, s), -1, np.int32)
+    ids[0, :real] = 0
+    seg = jnp.asarray(ids)
+    q, k, v, dy = _inputs(jax.random.PRNGKey(8), 1, 2, 2, s, s, jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, seg, seg, causal=False, q_block=128, kv_block=128, interpret=True
+    )
+    ref = lambda q, k, v: attention_reference(
+        q, k, v, causal=False, q_segment_ids=seg, kv_segment_ids=seg
+    )
+    o = flash(q, k, v)
+    assert _rel_err(o, ref(q, k, v)) < 1e-5
+    assert float(jnp.max(jnp.abs(o[:, :, real:]))) == 0.0
+    for g_p, g_r in zip(_grads(flash, q, k, v, dy), _grads(ref, q, k, v, dy)):
+        assert _rel_err(g_p, g_r) < 1e-5
+        assert float(jnp.max(jnp.abs(g_p[:, :, real:]))) == 0.0
+    # q tiles 0-1 against kv tiles 0-1 hold real pairs; the 12 others hold
+    # padding only on one side or both
+    assert attention_tile_counts(
+        seg, seg, q_block=128, kv_block=128, causal=False
+    ) == (4, 16)
+
+
+# -- shape-chosen tiles at the Wan buckets ------------------------------------
+
+WAN_LENGTHS = (1024, 1560, 3600, 4680, 7800)
+WAN_TEXT = 512
+# (sq, skv) -> (q_block, sq padded, kv_block, skv padded), bf16, bidirectional
+WAN_TILES = {
+    (1024, 1024): (1024, 1024, 1024, 1024),
+    (1560, 1560): (784, 1568, 1560, 1560),
+    (3600, 3600): (400, 3600, 3600, 3600),
+    (4680, 4680): (432, 4752, 4680, 4680),
+    (7800, 7800): (224, 7840, 7800, 7800),
+    (1024, 512): (1024, 1024, 512, 512),
+    (1560, 512): (1560, 1560, 512, 512),
+    (3600, 512): (3600, 3600, 512, 512),
+    (4680, 512): (2352, 4704, 512, 512),
+    (7800, 512): (3904, 7808, 512, 512),
+}
+
+
+@pytest.mark.parametrize(
+    "sq,skv",
+    [(s, s) for s in WAN_LENGTHS] + [(s, WAN_TEXT) for s in WAN_LENGTHS],
+    ids=lambda x: str(x),
+)
+def test_tile_rule_at_wan_buckets(sq, skv):
+    """Self-attention at every bucket of the benchmark's cells and
+    cross-attention to the text tokens: the rule's tiles, at most 1/8 of
+    the grid steps of 128x128 tiles, and an executed tile area (padding-only
+    tiles skipped) within 1.15x the real Sq*Skv."""
+    qb, sq_p, kb, skv_p = choose_tiles(sq, skv, jnp.dtype(jnp.bfloat16), False)
+    assert (qb, sq_p, kb, skv_p) == WAN_TILES[(sq, skv)]
+    assert sq_p % qb == 0 and skv_p % kb == 0
+    assert qb % 16 == 0 or qb == sq_p
+    assert kb % 128 == 0 or kb == skv_p == skv
+    steps = (sq_p // qb) * (skv_p // kb)
+    assert 8 * steps <= -(-sq // 128) * -(-skv // 128)
+    qs = np.full((1, sq_p), -1, np.int32)
+    qs[0, :sq] = 0
+    ks = np.full((1, skv_p), -1, np.int32)
+    ks[0, :skv] = 0
+    executed, total = attention_tile_counts(
+        qs, ks, q_block=qb, kv_block=kb, causal=False
+    )
+    assert total == steps
+    assert executed * qb * kb <= 1.15 * sq * skv
+
+
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_shape_chosen_tiles_parity_ragged(dt):
+    """A ragged Wan length (S=1560) under the shape-chosen tiles: forward
+    and all three gradients match the oracle at the acceptance tolerances."""
+    tol = 1e-5 if dt == jnp.float32 else 1e-3
+    q, k, v, dy = _inputs(jax.random.PRNGKey(9), 1, 1, 1, 1560, 1560, dt)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=False, interpret=True)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=False)
+    assert _rel_err(flash(q, k, v), ref(q, k, v)) < tol
+    for name, g_p, g_r in zip("qkv", _grads(flash, q, k, v, dy), _grads(ref, q, k, v, dy)):
+        err = _rel_err(g_p, g_r)
+        assert err < tol, f"d{name} rel err {err} >= {tol}"
+
+
 # -- blocked_attention (jnp oracle path) -------------------------------------
 
 
@@ -301,13 +390,29 @@ def test_attention_dispatcher_rejects_ungroupable_heads():
 
 
 def test_ragged_padding_uses_lane_granule():
-    """sq=300 must pad to 384 (128-tiles), not 512 (one mostly-pad 256-tile);
-    values stay exact either way."""
+    """With no blocks given the tiles follow the length: sq=300 pads its q
+    side only to the sublane granule and its kv side only to the lane
+    granule (or not at all, as one whole-length kv tile), never to a
+    mostly-padding block; values stay exact either way."""
     q, k, v, _ = _inputs(jax.random.PRNGKey(7), 1, 1, 1, 300, 300, jnp.float32)
     o = flash_attention(q, k, v, causal=True, interpret=True)  # default blocks
     o_r = attention_reference(q, k, v, causal=True)
     assert o.shape == q.shape
     assert _rel_err(o, o_r) < 1e-5
+
+
+def test_explicit_blocks_pad_to_lane_granule():
+    """A caller's blocks are kept: sq=300 with 256-blocks pads both sides to
+    384 and runs 128-tiles, the padded kv columns masked by segment ids the
+    wrapper makes; values and gradients stay exact."""
+    q, k, v, dy = _inputs(jax.random.PRNGKey(10), 1, 1, 1, 300, 300, jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=False, q_block=256, kv_block=256, interpret=True
+    )
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=False)
+    assert _rel_err(flash(q, k, v), ref(q, k, v)) < 1e-5
+    for g_p, g_r in zip(_grads(flash, q, k, v, dy), _grads(ref, q, k, v, dy)):
+        assert _rel_err(g_p, g_r) < 1e-5
 
 
 def test_pack_documents_rejects_oversize_docs():

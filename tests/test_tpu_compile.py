@@ -117,6 +117,24 @@ def test_flash_fwd_bwd(one_chip, case):
     assert _compile(_grads(attn, (0, 1, 2)), *args) == 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize(
+    "b,s,skv",
+    [(1, 7800, 7800), (5, 1560, 1560), (1, 7800, TEXT), (5, 1560, TEXT)],
+    ids=["self_7800", "self_1560", "cross_7800", "cross_1560"],
+)
+def test_flash_fwd_bwd_wan_buckets(one_chip, b, s, skv):
+    """The shape-chosen tiles at the longest and the batched Wan bucket,
+    12 heads, bf16: a tile whose kernels overflow VMEM fails here."""
+    q = jax.ShapeDtypeStruct((b, H, s, DH), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, H, skv, DH), jnp.bfloat16, sharding=one_chip)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=False)
+
+    assert _compile(attn, q, kv, kv) == 1
+    assert _compile(_grads(attn, (0, 1, 2)), q, kv, kv) == 3  # fwd, dq, dkv
+
+
 @pytest.mark.parametrize("hq,hkv", [(16, 4), (4, 2)], ids=["gqa4", "gqa2"])
 def test_paged_decode(one_chip, hq, hkv):
     b, ps, pool, pages_max = 8, 16, 257, 64
