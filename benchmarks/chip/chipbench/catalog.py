@@ -1,12 +1,15 @@
-"""Find a cell, its configuration, its traffic and its per-layer metrics by
-the names in ``BENCHMARK.json``.
+"""Find a cell, its configuration, its model family, its traffic and its
+per-layer metrics by the names in ``BENCHMARK.json``.
 
 Each lives in a file of its own: ``configs/<config>.json`` (as named by
-the configuration's ``file``), ``traffic/<traffic>.json``,
-``limits/<cell>.json`` (the limits of the numbers that decide
-``correct``), ``windows/<cell>.json`` (the window's fixed number of steps)
-and ``metrics/<metric>.py``.  Adding a cell or a metric adds files and
-entries; no file here changes.
+the configuration's ``file``), ``chipbench/families/<family>.py`` (as
+named by the configuration's ``family``: widths, work counts, the plain
+reference model and how the program is built for it),
+``traffic/<traffic>.json``, ``limits/<cell>.json`` (the limits of the
+numbers that decide ``correct``), ``windows/<cell>.json`` (the window's
+fixed number of steps) and ``metrics/<metric>.py``.  Adding a cell, a
+metric or a configuration of a new family adds files and entries; no file
+here changes.
 """
 
 from __future__ import annotations
@@ -14,10 +17,10 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable
-
-from .work import Dims
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
@@ -29,6 +32,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict  # the configuration file
+    family: ModuleType  # chipbench/families/<family>.py
     traffic_name: str
     traffic: dict  # the traffic file
     end_to_end: list[dict]  # BENCHMARK.json entries this cell reports
@@ -37,25 +41,8 @@ class Cell:
     window: dict  # {"steps": n, "seconds": s}: n steps last about s seconds
 
     @property
-    def dims(self) -> Dims:
-        return dims_of(self.config)
-
-
-def dims_of(config: dict) -> Dims:
-    """The widths of a Wan configuration file, in the program's terms
-    (one token's input width is the VAE's channels times the patch)."""
-    pt, ph, pw = config["patch_size"]
-    return Dims(
-        d=config["dim"],
-        heads=config["num_heads"],
-        head_dim=config["dim"] // config["num_heads"],
-        ffn=config["ffn_dim"],
-        layers=config["num_layers"],
-        text_len=config["text_len"],
-        text_dim=config["text_dim"],
-        patch_in=config["in_dim"] * pt * ph * pw,
-        freq_dim=config["freq_dim"],
-    )
+    def dims(self):
+        return self.family.dims_of(self.config)
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -87,23 +74,42 @@ def find_cell(name: str, root: Path = ROOT, bench: dict | None = None) -> Cell:
     bench_dir = traffic_file.parents[1]
     limits = json.loads((bench_dir / "limits" / f"{name}.json").read_text())
     window = json.loads((bench_dir / "windows" / f"{name}.json").read_text())
+    family = load_family(config["family"], bench_dir)
     e2e = [m for m in bench["end_to_end"] if _reports(m, name, None)]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _reports(m, name, e2e_names)]
     return Cell(
-        name=name, chips=w["chips"], config_name=w["config"], config=config,
+        name=name, chips=w["chips"], config_name=w["config"], config=config, family=family,
         traffic_name=w["traffic"], traffic=traffic,
         end_to_end=e2e, per_layer=per_layer, limits=limits, window=window,
     )
+
+
+def _load(path: Path, module_name: str, missing: str) -> ModuleType:
+    """The Python file ``path`` as the module ``module_name``, imported once
+    a process (a module that defines dataclasses must be in
+    ``sys.modules``); ``KeyError(missing)`` if there is no such file."""
+    path = Path(path).resolve()
+    if not path.is_file():
+        raise KeyError(f"{missing} at {path}")
+    module = sys.modules.get(module_name)
+    if module is not None and module.__file__ == str(path):
+        return module
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[module_name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_family(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The model family ``name``, from ``chipbench/families/<name>.py``."""
+    path = Path(bench_dir) / "chipbench" / "families" / f"{name}.py"
+    return _load(path, f"chipbench_family_{name}", f"no family {name!r}")
 
 
 def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable[[dict], float | None]:
     """``read(run) -> value or None`` of the per-layer metric ``name``, from
     ``metrics/<name>.py``."""
     path = Path(bench_dir) / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise KeyError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load(path, f"chipbench_metric_{name}", f"no reader for metric {name!r}").read

@@ -93,13 +93,12 @@ class GcWatch:
 
 class Feed:
     """The iterator ``Trainer.run`` consumes: ``first`` items, then the
-    loader's, each ``next()`` timed and marked as a host span
+    loader's, each ``next()`` marked as a host span
     (``bench.loader_next``)."""
 
     def __init__(self, loader, first=()):
         self.loader = loader
         self.first = list(first)
-        self.waits: list[float] = []
         self.items: list[list[tuple[int, int]]] = []  # (B, S) per microbatch
         self.on_next = None
 
@@ -109,10 +108,8 @@ class Feed:
     def __next__(self):
         if self.on_next is not None:
             self.on_next(len(self.items))
-        t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.loader_next"):
             item = self.first.pop(0) if self.first else next(self.loader)
-        self.waits.append(time.perf_counter() - t0)
         self.items.append([(b.batch_size, b.seq_len) for b, _ in item])
         return item
 
@@ -160,27 +157,13 @@ def program_config(cell):
     """The program's ``ModelConfig`` and ``OptimizerConfig`` for a cell, and
     the launcher arguments of its traffic.  The config file names the
     program's config of the model (``program.config``) and the launcher
-    ``--arch`` whose optimizer it trains with (``program.arch``); every
-    width of the file is checked against the program's config."""
-    import dataclasses
-    import importlib
-
+    ``--arch`` whose optimizer it trains with (``program.arch``); the
+    cell's family checks every width of the file against the program's
+    config."""
     from repro.launch.train import optimizer_for
 
     c = cell.config
-    dims = cell.dims
-    module, fn = c["program"]["config"].split(":")
-    cfg = getattr(importlib.import_module(module), fn)()
-    want = {
-        "d_model": dims.d, "n_heads": dims.heads, "n_kv_heads": dims.heads,
-        "head_dim": dims.head_dim, "d_ff": dims.ffn, "text_len": dims.text_len,
-        "in_channels": c["in_dim"], "dtype": c["param_dtype"],
-        "opt_state_dtype": c["optimizer"]["state_dtype"], "norm_eps": c["eps"],
-    }
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"config file and program disagree: {want} != {got}")
-    cfg = dataclasses.replace(cfg, n_layers=dims.layers)
+    cfg = cell.family.program_model(c)
     args = _launcher_args(cell)
     opt = optimizer_for(args, cfg)
     stated = c["optimizer"]
@@ -214,9 +197,7 @@ class TrainCell:
     # -- program objects ---------------------------------------------------
 
     def _make_batch(self, rng, bucket):
-        from repro.data.synthetic import make_diffusion_batch
-
-        return make_diffusion_batch(
+        return self.cell.family.program_batch(
             seeds.batch_key(self.seed, int(rng.integers(2**31))),
             bucket.batch_size, bucket.seq_len, self.cfg,
         )
@@ -256,10 +237,9 @@ class TrainCell:
         stream of its own, so that every program the window runs compiles
         here and the comparison covers the longest sequence and a pool of
         several microbatches."""
-        from repro.data.synthetic import make_diffusion_batch
-
+        make = self.cell.family.program_batch
         return [
-            (b, make_diffusion_batch(
+            (b, make(
                 seeds.batch_key(self.seed, i, first=True), b.batch_size, b.seq_len,
                 self.cfg,
             ))
@@ -369,7 +349,6 @@ class TrainCell:
             if len(hw.step_times) != n_steps:
                 raise RuntimeError(f"the window ran {len(hw.step_times)} of {n_steps} steps")
             window_items = feed.items[first:first + n_steps]
-            waits = feed.waits[first:first + n_steps]
             memory = [d.memory_stats() or {} for d in jax.devices()[: self.cell.chips]]
         finally:
             feed.loader.close()
@@ -385,7 +364,6 @@ class TrainCell:
             "tokens": sum(hw.tokens),
             "microbatches": [mb for it in window_items for mb in it],
             "traced_microbatches": [mb for it in traced for mb in it],
-            "loader_wait_s": waits,
             "memory": memory,
             "trace_dir": trace_dir,
             "readings": readings,

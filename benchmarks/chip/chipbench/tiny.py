@@ -8,7 +8,7 @@ from __future__ import annotations
 import copy
 import json
 
-from .catalog import BENCH_DIR, Cell
+from .catalog import BENCH_DIR, Cell, load_family
 
 
 def tiny_config() -> dict:
@@ -32,7 +32,7 @@ TRAFFIC = {
 def tiny_cell(per_layer=()) -> Cell:
     return Cell(
         name="tiny", chips=1, config_name="wan2.1-1.3b", config=tiny_config(),
-        traffic_name="tiny", traffic=copy.deepcopy(TRAFFIC),
+        family=load_family("wan"), traffic_name="tiny", traffic=copy.deepcopy(TRAFFIC),
         end_to_end=[
             {"name": "tokens_per_s", "unit": "tokens/s"},
             {"name": "mfu", "unit": "%"},

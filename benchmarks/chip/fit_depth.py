@@ -49,14 +49,14 @@ def tree_bytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-def needs(cfg, opt, buckets, chip) -> dict:
+def needs(cfg, opt, buckets, chip, program_batch) -> dict:
     """Bytes of the update and of each bucket's grad step (with the state)
-    at ``cfg``'s depth, compiled for the described ``chip``."""
+    at ``cfg``'s depth, compiled for the described ``chip``;
+    ``program_batch`` is the cell's family's."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from repro.data.synthetic import make_diffusion_batch
     from repro.train.steps import make_pool_grad_step, make_pool_update, state_shapes
 
     sh = SingleDeviceSharding(chip)
@@ -81,7 +81,7 @@ def needs(cfg, opt, buckets, chip) -> dict:
     idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=sh)
     for b in buckets:
         batch = placed(jax.eval_shape(
-            lambda k, b=b: make_diffusion_batch(k, b.batch_size, b.seq_len, cfg),
+            lambda k, b=b: program_batch(k, b.batch_size, b.seq_len, cfg),
             jax.random.PRNGKey(0),
         ))
         compiled = compile_fitting(step.lower(st["params"], batch, key, idx))
@@ -116,7 +116,8 @@ def main() -> None:
     published = cell.config["published"]["num_layers"]
 
     def fits(depth: int) -> bool:
-        n = needs(dataclasses.replace(cfg, n_layers=depth), opt, buckets, chip)
+        n = needs(dataclasses.replace(cfg, n_layers=depth), opt, buckets, chip,
+                  cell.family.program_batch)
         print(json.dumps({"depth": depth, "bytes": n, "max": max(n.values())}), flush=True)
         return max(n.values()) <= args.budget
 

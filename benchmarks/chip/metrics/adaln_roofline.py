@@ -2,8 +2,6 @@
 steps need (forward and backward, bound by HBM bytes) over the summed
 trace time of the AdaLN kernels (forward, dx, dmod)."""
 
-from chipbench.work import step_kernel_seconds
-
 
 def read(run: dict) -> float | None:
     trace = run["trace"]
@@ -13,7 +11,7 @@ def read(run: dict) -> float | None:
     if spent <= 0:
         return None
     peaks = run["peaks"]
-    least = step_kernel_seconds(
+    least = run["family"].step_kernel_seconds(
         run["dims"], run["traced_microbatches"],
         peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"],
     )["adaln"]

@@ -3,8 +3,6 @@
 no recomputation) over the summed trace time of the flash kernels
 (forward, dq, dkv)."""
 
-from chipbench.work import step_kernel_seconds
-
 
 def read(run: dict) -> float | None:
     trace = run["trace"]
@@ -14,7 +12,7 @@ def read(run: dict) -> float | None:
     if spent <= 0:
         return None
     peaks = run["peaks"]
-    least = step_kernel_seconds(
+    least = run["family"].step_kernel_seconds(
         run["dims"], run["traced_microbatches"],
         peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"],
     )["flash"]

@@ -4,12 +4,14 @@
 
 From the root of a checkout.  The cell, its configuration, its traffic and
 its per-layer metrics are found by name from ``BENCHMARK.json`` and the
-files under ``benchmarks/chip/``.  With ``--trace 0`` the result holds the
-cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics, read
-from a profiler trace of the window's last steps.  Every run checks the
-steps it compared against the plain reference (``chipbench/reference.py``)
-and prints each compared number beside its limit, last on standard error
-and last in the result line.
+files under ``benchmarks/chip/``; the configuration names its model
+family, whose file gives the work counts and the reference model.  With
+``--trace 0`` the result holds the cell's end-to-end metrics, with
+``--trace 1`` its per-layer metrics, read from a profiler trace of the
+window's last steps.  Every run checks the steps it compared against the
+plain reference (``chipbench/reference.py`` with the family's model) and
+prints each compared number beside its limit, last on standard error and
+last in the result line.
 
 The run fails, and prints no result, without a TPU or with fewer chips
 than the cell asks for, and when anything compiles inside the window.
@@ -94,10 +96,8 @@ def finite(x: float):
 
 
 def end_to_end(cell, run, peaks) -> dict:
-    from chipbench.work import model_flops
-
     dims = cell.dims
-    flops = sum(model_flops(dims, b, s) for b, s in run["microbatches"])
+    flops = sum(cell.family.model_flops(dims, b, s) for b, s in run["microbatches"])
     values = {
         "tokens_per_s": run["tokens"] / run["window_s"],
         "mfu": 100.0 * flops / (run["window_s"] * cell.chips * peaks["bf16_flops_per_s"]),
@@ -109,7 +109,8 @@ def end_to_end(cell, run, peaks) -> dict:
 def per_layer(cell, run, peaks, reduced) -> dict:
     from chipbench import catalog
 
-    record = {**run, "trace": reduced, "dims": cell.dims, "peaks": peaks, "chips": cell.chips}
+    record = {**run, "trace": reduced, "family": cell.family, "dims": cell.dims,
+              "peaks": peaks, "chips": cell.chips}
     out = {}
     for m in cell.per_layer:
         value = catalog.metric_reader(m["name"])(record)
@@ -121,7 +122,6 @@ def per_layer(cell, run, peaks, reduced) -> dict:
 def run_cell(args, cell, devices, peaks) -> None:
     """Run ``cell`` once on ``devices`` and print its result line."""
     from chipbench import harness, seeds, verdict, xtrace
-    from chipbench.reference import Reference
 
     run = harness.TrainCell(cell, args.seed).run(args.seconds, bool(args.trace), T_START, log)
     log(f"window: {run['window_s']:.3f} s, {run['steps']} steps, {run['tokens']} tokens")
@@ -134,11 +134,7 @@ def run_cell(args, cell, devices, peaks) -> None:
             shutil.rmtree(run["trace_dir"], ignore_errors=True)
 
     t0 = time.perf_counter()
-    ref = Reference(
-        cell.dims, harness.optimizer_dict(cell.config), dtype=cell.config["param_dtype"]
-    ).run(
-        seeds.init_key(args.seed), harness.check_steps(cell, args.seed)
-    )
+    ref = reference(cell).run(seeds.init_key(args.seed), harness.check_steps(cell, args.seed))
     log(f"reference: {time.perf_counter() - t0:.1f} s")
     correct, checks = verdict.judge(verdict.numbers(run["readings"], ref), cell.limits)
 
@@ -166,6 +162,16 @@ def run_cell(args, cell, devices, peaks) -> None:
     print(json.dumps(result), flush=True)
 
 
+def reference(cell, **control):
+    """The plain reference of ``cell``'s model; ``control`` as
+    ``chipbench.reference.Reference`` takes it."""
+    from chipbench import harness
+    from chipbench.reference import Reference
+
+    return Reference(cell.family, cell.dims, harness.optimizer_dict(cell.config),
+                     dtype=cell.config["param_dtype"], **control)
+
+
 def run_seeds(args, cell) -> None:
     """Readings against the reference, one JSON line per seed, with no
     window: under ``program`` the set-up's compared steps (the state then
@@ -173,23 +179,20 @@ def run_seeds(args, cell) -> None:
     the float8 control and the half-batch fault for the ``--control``
     seeds.  The reference runs once a seed."""
     from chipbench import harness, seeds, verdict
-    from chipbench.reference import Reference
 
-    opt = harness.optimizer_dict(cell.config)
-    dtype = cell.config["param_dtype"]
     readings, control = args.readings or [], args.control or []
     for seed in dict.fromkeys(readings + control):
         steps = harness.check_steps(cell, seed)
         out = {"seed": seed}
         prog = harness.TrainCell(cell, seed).readings(log) if seed in readings else None
         t0 = time.perf_counter()
-        ref = Reference(cell.dims, opt, dtype=dtype).run(seeds.init_key(seed), steps)
+        ref = reference(cell).run(seeds.init_key(seed), steps)
         log(f"seed {seed} reference: {time.perf_counter() - t0:.1f} s")
         if prog is not None:
             out["program"] = verdict.numbers(prog, ref)
         if seed in control:
             for name, kw in (("fp8", {"matmul": "fp8"}), ("half_batch", {"half_rows": True})):
-                other = Reference(cell.dims, opt, dtype=dtype, **kw).run(seeds.init_key(seed), steps)
+                other = reference(cell, **kw).run(seeds.init_key(seed), steps)
                 out[name] = verdict.numbers(other, ref)
         log(f"seed {seed}: {out}")
         print(json.dumps(out), flush=True)

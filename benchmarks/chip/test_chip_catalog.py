@@ -1,12 +1,13 @@
-"""Cells, configurations, traffic and per-layer metrics are found by name
-from files; adding a cell or a metric edits no file; the command refuses a
-platform without a TPU."""
+"""Cells, configurations, model families, traffic and per-layer metrics are
+found by name from files; adding a cell, a metric or a configuration of a
+new family edits no file; the command refuses a platform without a TPU."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
 import shutil
+import types
 
 import pytest
 
@@ -35,7 +36,7 @@ def test_every_cell_is_found_with_its_files(name):
 @pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
 def test_every_metric_has_a_reader_that_reads_nothing_from_nothing(name):
     read = catalog.metric_reader(name)
-    empty = {"trace": None, "loader_wait_s": [], "memory": [], "traced_microbatches": []}
+    empty = {"trace": None, "memory": [], "traced_microbatches": []}
     assert read(empty) is None
 
 
@@ -85,6 +86,71 @@ def test_adding_a_cell_and_a_metric_edits_no_file(tmp_path):
     assert "steps_seen" not in [m["name"] for m in catalog.find_cell("wan13b.mix", tmp_path).per_layer]
     read = catalog.metric_reader("steps_seen", tmp_path / bench_rel)
     assert read({"steps": 7}) == 7.0
+    for path, data in before.items():
+        assert path.read_bytes() == data, f"{path} changed"
+
+
+STUB_FAMILY = '''"""A stand-in family: one width, two FLOPs a token."""
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class Dims:
+    d: int
+    layers: int
+
+
+def dims_of(config):
+    return Dims(d=config["hidden_size"], layers=config["num_layers"])
+
+
+def model_flops(dims, b, s):
+    return 2 * b * s * dims.d * dims.layers
+'''
+
+
+def test_adding_a_configuration_of_a_new_family_edits_no_file(tmp_path):
+    bench_rel = BENCH["paths"][0]
+    bench_dir = tmp_path / bench_rel
+    shutil.copytree(catalog.BENCH_DIR, bench_dir, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in bench_dir.rglob("*") if p.is_file()}
+    bench = json.loads(json.dumps(BENCH))
+    # new files: the family, its configuration, a traffic mix, the cell's
+    # limits and window
+    (bench_dir / "chipbench" / "families" / "stub.py").write_text(STUB_FAMILY)
+    (bench_dir / "configs" / "stub-1b.json").write_text(json.dumps({
+        "name": "stub-1b", "source": "https://example.org/stub-1b", "family": "stub",
+        "hidden_size": 3072, "num_layers": 2, "published": {"num_layers": 60},
+        "reduced": ["num_layers"],
+    }))
+    (bench_dir / "traffic" / "clips.json").write_text(json.dumps({
+        "kind": "train", "media": [[33, 480, 832]], "weights": [1.0],
+        "launcher": ["--adaptive", "--batch", "8", "--seq", "512"],
+    }))
+    (bench_dir / "limits" / "stub.clips.json").write_text(
+        json.dumps({"loss_gap": 1e-3, "grad_gap": 1e-3, "change_gap": 1e-3})
+    )
+    (bench_dir / "windows" / "stub.clips.json").write_text(json.dumps({"steps": 10, "seconds": 30}))
+    # new entries in BENCHMARK.json
+    bench["configs"].append({"name": "stub-1b", "source": "https://example.org/stub-1b",
+                             "file": f"{bench_rel}/configs/stub-1b.json",
+                             "reduced": ["num_layers"], "why": "a family of its own"})
+    bench["workloads"].append({"name": "stub.clips", "config": "stub-1b", "traffic": "clips",
+                               "chips": 1, "why": "clips only"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = catalog.find_cell("stub.clips", tmp_path)
+    assert cell.family.__doc__.startswith("A stand-in family")
+    assert (cell.dims.d, cell.dims.layers) == (3072, 2)
+    assert "step_mfu" in [m["name"] for m in cell.per_layer]
+    # the readers reach the counts through the cell's family
+    trace = types.SimpleNamespace(window_s=1.0)
+    record = {"trace": trace, "family": cell.family, "dims": cell.dims, "chips": 1,
+              "peaks": {"bf16_flops_per_s": 1e12}, "traced_microbatches": [(1, 1000)]}
+    assert catalog.metric_reader("step_mfu", bench_dir)(record) == pytest.approx(
+        100.0 * 2 * 1000 * 3072 * 2 / 1e12
+    )
+    assert catalog.find_cell("wan13b.mix", tmp_path).family.__name__ == "chipbench_family_wan"
     for path, data in before.items():
         assert path.read_bytes() == data, f"{path} changed"
 

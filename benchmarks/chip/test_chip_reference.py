@@ -1,6 +1,7 @@
-"""The plain reference makes the program's weights, data and noise from the
-seed and agrees with the program's loss at small size on the CPU; its
-float8 control and the half-batch fault fail the 1.3B cell's limits."""
+"""The plain reference (the Wan family's model) makes the program's
+weights, data and noise from the seed and agrees with the program's loss
+at small size on the CPU; its float8 control and the half-batch fault fail
+the 1.3B cell's limits."""
 
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ from chipbench import harness, reference, seeds, verdict
 from chipbench.tiny import tiny_cell
 
 CELL = tiny_cell()
+WAN = CELL.family
 DIMS = CELL.dims
 OPT = harness.optimizer_dict(CELL.config)
 
@@ -32,14 +34,14 @@ def test_weights_and_data_are_the_programs(dtype):
 
     cfg = _program_cfg(dtype)
     key = seeds.init_key(2**33 + 1)
-    ours = reference.init_params(key, DIMS, jnp.dtype(dtype))
+    ours = WAN.init_params(key, DIMS, jnp.dtype(dtype))
     theirs = init_params(key, cfg)
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
     bkey = seeds.batch_key(5, 123)
-    ours = reference.make_batch(bkey, 2, 64, DIMS, jnp.dtype(dtype))
+    ours = WAN.make_batch(bkey, 2, 64, DIMS, jnp.dtype(dtype))
     theirs = make_diffusion_batch(bkey, 2, 64, cfg)
     for k in ("latents", "text"):
         np.testing.assert_array_equal(np.asarray(ours[k], np.float32),
@@ -61,13 +63,13 @@ def test_loss_and_gradient_match_the_program_in_fp32():
 def _compare_with_program(init_params, rectified_flow_loss):
     cfg = _program_cfg("float32")
     params = init_params(seeds.init_key(3), cfg)
-    batch = reference.make_batch(seeds.batch_key(3, 1), 2, 64, DIMS, jnp.float32)
+    batch = WAN.make_batch(seeds.batch_key(3, 1), 2, 64, DIMS, jnp.float32)
     rng = jax.random.PRNGKey(9)
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.value_and_grad(
             lambda p: rectified_flow_loss(p, cfg, batch["latents"], batch["text"], rng)
         )(params)
-        lr, gr = jax.value_and_grad(lambda p: reference.loss(p, DIMS, batch, rng))(params)
+        lr, gr = jax.value_and_grad(lambda p: WAN.loss(p, DIMS, batch, rng))(params)
     assert float(lr) == pytest.approx(float(lp), rel=1e-5)
     for a, b in zip(jax.tree.leaves(gr), jax.tree.leaves(gp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=1e-6)
@@ -78,8 +80,8 @@ def _compare_with_program(init_params, rectified_flow_loss):
 def test_control_and_fault_fail_the_limits(fault):
     seed = 2**32 + 77
     steps = harness.check_steps(CELL, seed)
-    ref = reference.Reference(DIMS, OPT, dtype=jnp.float32).run(seeds.init_key(seed), steps)
-    other = reference.Reference(DIMS, OPT, dtype=jnp.float32, **fault).run(
+    ref = reference.Reference(WAN, DIMS, OPT, dtype=jnp.float32).run(seeds.init_key(seed), steps)
+    other = reference.Reference(WAN, DIMS, OPT, dtype=jnp.float32, **fault).run(
         seeds.init_key(seed), steps
     )
     nums = verdict.numbers(other, ref)

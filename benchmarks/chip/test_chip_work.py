@@ -1,4 +1,5 @@
-"""Work counts against hand sums at Wan-2.1 widths, and the peak table."""
+"""Work counts against hand sums at Wan-2.1 widths (the Wan family's step,
+each kernel call's work), and the peak table."""
 
 from __future__ import annotations
 
@@ -6,13 +7,16 @@ import json
 
 import pytest
 
-from chipbench.catalog import BENCH_DIR, dims_of
+from chipbench.catalog import BENCH_DIR, load_family
 from chipbench.peaks import peaks_for
-from chipbench.work import adaln_work, flash_work, least_seconds, model_flops
+from chipbench.work import adaln_work, flash_work, least_seconds
+
+WAN = load_family("wan")
+model_flops = WAN.model_flops
 
 
 def _dims(name):
-    return dims_of(json.loads((BENCH_DIR / "configs" / f"{name}.json").read_text()))
+    return WAN.dims_of(json.loads((BENCH_DIR / "configs" / f"{name}.json").read_text()))
 
 
 def test_dims_of_the_configs():
